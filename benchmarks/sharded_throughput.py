@@ -166,6 +166,9 @@ def run(samples: int = 512, seed: int = 0, devices: int = 8,
     code = f"PARAMS = {params!r}\n" + SUBPROC_SNIPPET
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # virtual CPU devices by design; and a chip belongs to one process,
+    # which may be this parent (benchmarks/run.py imports jax)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=3000,
                           env=env)

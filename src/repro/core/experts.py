@@ -674,7 +674,9 @@ class ModelExpert:
     XLA's runtime threads don't survive forking).  The executor is
     sized to ``max(workers, max_workers)`` so autoscaling up never
     needs a pool rebuild; a broken process pool (a child died) is
-    detected and rebuilt on the next submit.
+    detected and rebuilt on the next submit.  The process backend is
+    refused off the CPU backend: an accelerator belongs to one process,
+    and the parent already holds it.
     """
 
     params: dict
@@ -694,6 +696,14 @@ class ModelExpert:
         if self.backend not in ("thread", "process"):
             raise ValueError(f"backend must be 'thread' or 'process', "
                              f"got {self.backend!r}")
+        if self.backend == "process" and jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"ModelExpert(backend='process') on a "
+                f"{jax.default_backend()!r} backend: an accelerator "
+                f"belongs to one process, and this one already holds it, "
+                f"so spawned annotator children would fail or hang "
+                f"reaching the device. Use backend='thread' (one process "
+                f"per chip); the process pool is for CPU-only runs.")
         self.auto_workers = self.workers == "auto"
         self.workers = 1 if self.auto_workers else max(int(self.workers), 1)
         self._lock = threading.RLock()

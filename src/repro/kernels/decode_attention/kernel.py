@@ -36,12 +36,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)              # (bkv, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    pos = pos_ref[0]                                    # (bkv,)
+    k = k_ref[0, 0].astype(jnp.float32)                 # (bkv, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
+    pos = pos_ref[0, 0]                                 # (1, bkv)
 
     s = q @ k.T                                         # (G, bkv)
-    valid = (pos >= 0)[None, :]
+    valid = pos >= 0
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
@@ -58,19 +58,21 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref,
         o_ref[0, 0] = (acc_scr[...] / l_fin).astype(o_ref.dtype)
 
 
-def decode_attention_grouped(q, k, v, pos, *, block_kv: int = 512,
-                             sm_scale=None, interpret: bool = True):
-    """q: (B, K, G, hd) one token per batch, G = q-heads per kv head.
-    k, v: (B, W, K, hd) ring caches; pos: (B, W) slot positions (-1 empty).
+def decode_attention_grouped(q, k, v, pos, *, block_kv: int,
+                             sm_scale: float, interpret: bool = True):
+    """Kernel layout: q (B, K, G, hd) one token per batch, G = q-heads
+    per kv head; k, v (B, K, W, hd) ring caches, head-major; pos
+    (B, n_kv, 1, block_kv) slot positions (-1 empty), one row per kv
+    block.  Every block's last two dims are either (8, 128)-aligned or
+    the whole array dims, as the TPU lowering requires.
 
-    Returns (B, K, G, hd).
+    Returns (B, K, G, hd).  ``ops.decode_attention`` adapts the model
+    layout.
     """
     B, K, G, hd = q.shape
-    W = k.shape[1]
-    block_kv = min(block_kv, W)
-    assert W % block_kv == 0
+    W = k.shape[2]
     n_kv = W // block_kv
-    sm_scale = sm_scale if sm_scale is not None else hd ** -0.5
+    assert n_kv * block_kv == W and pos.shape == (B, n_kv, 1, block_kv)
 
     kernel = functools.partial(_decode_kernel, n_kv_blocks=n_kv,
                                sm_scale=sm_scale)
@@ -79,11 +81,12 @@ def decode_attention_grouped(q, k, v, pos, *, block_kv: int = 512,
         grid=(B, K, n_kv),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd),
-                         lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd),
-                         lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_kv), lambda b, h, ki: (b, ki)),
+            pl.BlockSpec((1, 1, block_kv, hd),
+                         lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_kv, hd),
+                         lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, 1, block_kv),
+                         lambda b, h, ki: (b, ki, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ki: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),

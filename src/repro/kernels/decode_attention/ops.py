@@ -33,8 +33,19 @@ def decode_attention(q, k, v, pos, *, block_kv: int = 512,
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, pad)])
         k = jnp.pad(k, [(0, 0)] * 3 + [(0, pad)])
         v = jnp.pad(v, [(0, 0)] * 3 + [(0, pad)])
+    # kernel layout (see decode_attention_grouped): head-major caches,
+    # pos split into one (1, block_kv) row per kv block.  The transposes
+    # read and write the whole cache once more per call; a decode loop
+    # with a persistent cache should keep it head-major (B, K, W, hd) and
+    # call decode_attention_grouped directly.
+    W = k.shape[1]
+    block_kv = min(block_kv, W)
+    assert W % block_kv == 0
     qg = q[:, 0].reshape(B, K, G, q.shape[-1])
-    out = decode_attention_grouped(qg, k, v, pos, block_kv=block_kv,
+    kk = k.transpose(0, 2, 1, 3)
+    vk = v.transpose(0, 2, 1, 3)
+    posk = pos.reshape(B, W // block_kv, 1, block_kv)
+    out = decode_attention_grouped(qg, kk, vk, posk, block_kv=block_kv,
                                    sm_scale=sm_scale, interpret=interpret)
     out = out.reshape(B, 1, H, out.shape[-1])
     if pad:

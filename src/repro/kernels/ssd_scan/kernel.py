@@ -30,67 +30,73 @@ def _ssd_kernel(x_ref, adt_ref, dt_ref, b_ref, c_ref, y_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)     # (L, hp)
-    adt = adt_ref[0, 0, 0].astype(jnp.float32)  # (L,)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (L,)
+    adt = adt_ref[0, 0, 0].astype(jnp.float32)  # (1, L) row
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (1, L) row
     B = b_ref[0, 0].astype(jnp.float32)        # (L, N)
     C = c_ref[0, 0].astype(jnp.float32)        # (L, N)
 
-    cum = jnp.cumsum(adt)                      # (L,)
-    # intra-chunk: scores[i, j] = (C_i . B_j) * exp(cum_i - cum_j) * (i >= j)
+    # The TPU lowering has no cumsum and no 1-D vectors: prefix sums and
+    # row->column turns are masked lane/sublane reductions over (L, L)
+    # (exact f32 adds, no MXU pass that could round to bf16).
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(li >= lj, jnp.exp(cum[:, None] - cum[None, :]), 0.0)
+    causal = li >= lj
+    adt_col = jnp.sum(jnp.where(li == lj, adt, 0.0), axis=1, keepdims=True)
+    dt_col = jnp.sum(jnp.where(li == lj, dt, 0.0), axis=1, keepdims=True)
+    cum_col = jnp.sum(jnp.where(causal, adt, 0.0), axis=1,
+                      keepdims=True)          # (L, 1): cum_i
+    cum_row = jnp.sum(jnp.where(li <= lj, adt_col, 0.0), axis=0,
+                      keepdims=True)          # (1, L): cum_j
+    total = jnp.sum(adt, axis=1, keepdims=True)  # (1, 1): cum_L
+
+    # intra-chunk: scores[i, j] = (C_i . B_j) * exp(cum_i - cum_j) * (i >= j)
+    decay = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)
     cb = C @ B.T                               # (L, L)
-    y_intra = (cb * decay) @ (x * dt[:, None])
+    y_intra = (cb * decay) @ (x * dt_col)
 
     # inter-chunk: y_i += (C_i * exp(cum_i)) @ h_prev^T
     h_prev = h_scr[...]                        # (hp, N)
-    y_inter = (C * jnp.exp(cum)[:, None]) @ h_prev.T
+    y_inter = (C * jnp.exp(cum_col)) @ h_prev.T
 
     y_ref[0, 0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: h = h * exp(cum_L) + sum_j exp(cum_L - cum_j) dt_j x_j B_j^T
-    decay_out = jnp.exp(cum[-1] - cum)         # (L,)
-    xw = x * (decay_out * dt)[:, None]         # (L, hp)
-    h_scr[...] = h_prev * jnp.exp(cum[-1]) + xw.T @ B
+    xw = x * (jnp.exp(total - cum_col) * dt_col)  # (L, hp)
+    h_scr[...] = h_prev * jnp.exp(total) + jax.lax.dot_general(
+        xw, B, (((0,), (0,)), ((), ())))       # xw^T @ B: (hp, N)
 
 
-def ssd_scan_chunked(x, adt, dt, B, C, *, chunk: int = 256,
+def ssd_scan_chunked(xk, adtk, dtk, Bk, Ck, *,
                      interpret: bool = True) -> jax.Array:
-    """x: (Bsz, S, H, hp); adt, dt: (Bsz, S, H); B, C: (Bsz, S, N).
+    """Kernel layout: xk (Bsz, H, nc, L, hp); adtk, dtk (Bsz, H, nc, 1, L);
+    Bk, Ck (Bsz, nc, L, N).  Every block's last two dims are either
+    (8, 128)-aligned or the whole array dims, as the TPU lowering
+    requires — hence the unit axis that makes each chunk's decay row a
+    whole (1, L) tile.
 
-    Returns y: (Bsz, S, H, hp).  n_groups = 1 (B/C shared across heads).
+    Returns y (Bsz, H, nc, L, hp).  n_groups = 1 (B/C shared across
+    heads).  ``ops.ssd_scan`` adapts the model layout.
     """
-    Bsz, S, H, hp = x.shape
-    N = B.shape[-1]
-    chunk = min(chunk, S)
-    assert S % chunk == 0
-    nc = S // chunk
-
-    # kernel layouts: x (Bsz, H, nc, L, hp); adt/dt (Bsz, H, nc, L);
-    # B/C (Bsz, nc, L, N)
-    xk = x.reshape(Bsz, nc, chunk, H, hp).transpose(0, 3, 1, 2, 4)
-    adtk = adt.reshape(Bsz, nc, chunk, H).transpose(0, 3, 1, 2)
-    dtk = dt.reshape(Bsz, nc, chunk, H).transpose(0, 3, 1, 2)
-    Bk = B.reshape(Bsz, nc, chunk, N)
-    Ck = C.reshape(Bsz, nc, chunk, N)
+    Bsz, H, nc, chunk, hp = xk.shape
+    N = Bk.shape[-1]
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    yk = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(Bsz, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, hp),
                          lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, chunk, hp),
                                lambda b, h, c: (b, h, c, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bsz, H, nc, chunk, hp), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Bsz, H, nc, chunk, hp), xk.dtype),
         scratch_shapes=[pltpu.VMEM((hp, N), jnp.float32)],
         interpret=interpret,
     )(xk, adtk, dtk, Bk, Ck)
-    return yk.transpose(0, 2, 3, 1, 4).reshape(Bsz, S, H, hp)

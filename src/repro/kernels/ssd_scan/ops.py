@@ -19,5 +19,17 @@ def ssd_scan(x, adt, dt, B, C, *, chunk: int = 256,
     """Mamba2 SSD: x (Bsz,S,H,hp); adt/dt (Bsz,S,H); B/C (Bsz,S,N)."""
     if interpret is None:
         interpret = not _on_tpu()
-    return ssd_scan_chunked(x, adt, dt, B, C, chunk=chunk,
-                            interpret=interpret)
+    Bsz, S, H, hp = x.shape
+    N = B.shape[-1]
+    chunk = min(chunk, S)
+    assert S % chunk == 0
+    nc = S // chunk
+    # kernel layout (see ssd_scan_chunked): head-major chunks, each
+    # chunk's adt/dt as a (1, L) row
+    xk = x.reshape(Bsz, nc, chunk, H, hp).transpose(0, 3, 1, 2, 4)
+    adtk = adt.reshape(Bsz, nc, 1, chunk, H).transpose(0, 4, 1, 2, 3)
+    dtk = dt.reshape(Bsz, nc, 1, chunk, H).transpose(0, 4, 1, 2, 3)
+    Bk = B.reshape(Bsz, nc, chunk, N)
+    Ck = C.reshape(Bsz, nc, chunk, N)
+    yk = ssd_scan_chunked(xk, adtk, dtk, Bk, Ck, interpret=interpret)
+    return yk.transpose(0, 2, 3, 1, 4).reshape(Bsz, S, H, hp)

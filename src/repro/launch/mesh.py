@@ -6,24 +6,32 @@ jax device state (the dry-run sets XLA_FLAGS *before* any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """A Mesh with the given axis sizes/names (thin jax wrapper)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """A Mesh with the given axis sizes/names, every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which
+    ``with_sharding_constraint`` and the engine's lane gathers refuse;
+    the sharding rules here are written for the compiler-propagated
+    (``Auto``) mode, so every mesh of the repo is built through this.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist, as a (data, model) mesh with model = 1."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str):

@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,6 +29,7 @@ from repro.core import (BatchedCascadeEngine, OnlineCascade, SimulatedExpert,
                         default_cascade_config)
 from repro.core.experts import train_model_expert
 from repro.core.rng import tick_rngs
+from repro.launch.compile_cache import enable_compile_cache
 
 
 class _BatchProxy:
@@ -476,7 +478,11 @@ def main():
                          "in spawned processes (ModelExpert ships its "
                          "params to each child once) so a worker crash "
                          "cannot take the engine down — pair with "
-                         "--expert-timeout for full fault tolerance")
+                         "--expert-timeout for full fault tolerance. "
+                         "'process' is CPU-only: a chip belongs to one "
+                         "process, the server already holds it, and "
+                         "ModelExpert refuses to spawn children that "
+                         "would need it")
     ap.add_argument("--expert-timeout", type=float, default=None,
                     help="per-shard annotation deadline in seconds "
                          "(batched engine): a shard that misses it is "
@@ -586,6 +592,11 @@ def main():
                          "JSONL path after serving (requires "
                          "--sanitize determinism)")
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind!r} "
+          f"x{len(jax.devices())}; Pallas kernels "
+          f"{'Mosaic' if dev.platform == 'tpu' else 'interpret mode'}")
     modes = {m.strip() for m in args.sanitize.split(",") if m.strip()}
     if modes:
         _san.enable(modes)    # before engine build: jit probes hook in

@@ -20,7 +20,11 @@ path (``kernels/*/ref.py`` / ``models.ssm.ssd_chunked``).  The serving
 route pass predicts through the kernel path; the online-imitation loss
 differentiates through the reference path — ``pallas_call`` has no VJP,
 and the two paths are tolerance-pinned equal (tests/test_kernel_levels.py)
-so the gradient is taken on the same math the kernels compute.
+so the gradient is taken on the same math the kernels compute.  Both
+paths run every f32 matmul, inside the kernels too, at full float32
+precision (``MATMUL_PRECISION``): the TPU's default one-pass bf16 would
+put the paths ~1e-3 apart and serve a different model than the one
+trained.
 
 Shape/dtype contract (all float32 activations):
   tokens : (B, L) int32 hashed ids from ``data.features.hash_ids``;
@@ -32,6 +36,7 @@ sequence length — powers of two keep every default legal.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -45,6 +50,19 @@ from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models.layers import dense_init
 from repro.models.ssm import init_mamba, mamba_forward, ssd_chunked
+
+# f32 matmul precision of both students' forward passes, on both paths
+MATMUL_PRECISION = "float32"
+
+
+def _at_matmul_precision(fn):
+    """Trace ``fn`` under ``MATMUL_PRECISION`` (a fresh context per call:
+    one shared context object would not nest)."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
 @dataclass(frozen=True)
@@ -184,6 +202,7 @@ def _pool_readout(hf, pos_ids, params, spec: TinyTFFlashSpec,
     return pooled.reshape(B, d)
 
 
+@_at_matmul_precision
 def tinytf_flash_logits(params, tokens, spec: TinyTFFlashSpec,
                         use_kernels: bool = True):
     """tokens: (B, L) int32, 0 = pad (pads at the end) -> (B, C) logits.
@@ -267,6 +286,7 @@ def ssm_student_init(key, spec: SSMStudentSpec):
     }
 
 
+@_at_matmul_precision
 def ssm_student_logits(params, tokens, spec: SSMStudentSpec,
                        use_kernels: bool = True):
     """tokens: (B, L) int32, 0 = pad (pads at the end) -> (B, C) logits.

@@ -17,29 +17,18 @@ Aux losses (load-balance + router-z) are returned for the training objective.
 """
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import sharding as shd
 from repro.configs.base import ModelConfig
 from repro.models import flags
 from repro.models.layers import dense_init, trunc_normal
-
-try:  # jax >= 0.6 moved shard_map to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# jax >= 0.6 renamed check_rep -> check_vma; pass whichever this jax has
-# (without the flag, unreduced-psum replication checks reject the body)
-_SHARD_MAP_CHECK_KW = (
-    "check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep")
 
 
 def init_moe(key, cfg: ModelConfig):
@@ -222,12 +211,13 @@ def moe_ffn(x, params, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
             aux = jax.lax.pmean(aux, "model")
         return y.astype(x_loc.dtype), aux
 
-    y2d, aux = _shard_map(
+    # check_vma=False: the unreduced-psum replication check rejects the body
+    y2d, aux = shard_map(
         body, mesh=mesh,
         in_specs=(P(baxes, None), P(None, None), w_spec_in, w_spec_in,
                   w_spec_out),
         out_specs=(P(baxes, None), P()),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )(x2d, params["router"], params["w_in"], params["w_gate"],
       params["w_out"])
     return y2d.reshape(B, S, D), aux

@@ -506,3 +506,19 @@ def test_model_expert_process_backend_matches_thread():
         pr.close()
         th.close()
     assert pr._executor is None or pr._executor._shutdown_thread
+
+
+def test_model_expert_process_backend_refused_off_cpu(monkeypatch):
+    """A chip belongs to one process: off the CPU backend the parent
+    already holds the device, so backend="process" must refuse to start
+    rather than spawn children that would hang reaching it."""
+    from repro.core.experts import ModelExpert
+    from repro.models.students import tinytf_init, TinyTFSpec
+    import jax
+    spec = TinyTFSpec(vocab=64, max_len=8, d_model=16, n_heads=2,
+                      n_layers=1, d_ff=32, n_classes=2)
+    params = tinytf_init(jax.random.PRNGKey(0), spec)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process"):
+        ModelExpert(params=params, spec=spec, backend="process")
+    ModelExpert(params=params, spec=spec, backend="thread").close()

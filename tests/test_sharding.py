@@ -76,10 +76,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models import transformer as tf
 from repro import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.sharding import param_pspecs
 
 cfg = get_smoke_config({arch!r})
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 shd.set_mesh(mesh)
 params = jax.eval_shape(functools.partial(tf.init_params, cfg=cfg),
                         jax.random.PRNGKey(0))
