@@ -250,6 +250,24 @@ serving spends its life (benchmarks/pipelined_throughput.py measures
 both honestly).  Pipelined serving is driven through
 ``submit_tick``/``resolve_tick``/``drain`` (``run`` does); a tick's
 results return when it resolves, at most P ticks after submission.
+
+Host spans
+----------
+Each tick's host phases are ``jax.profiler.TraceAnnotation`` spans named
+``ocl.<phase>``, on the profiler's host plane and the device trace's
+clock.  They cost about a microsecond each and are recorded only while a
+profiler trace runs (``jax.profiler.trace(dir)`` around ``run``).  Every
+span carries ``tick`` (the routed tick; a commit also carries ``at``, the
+tick committing it) and the counts of its work as arguments:
+``ocl.route_dispatch``/``ocl.route_resolve`` (stages A and B),
+``ocl.draws`` (per-lane tick RNG), ``ocl.featurize`` (a level's feature
+batch), ``ocl.route_pass`` (pad, put and enqueue one level's forward:
+real ``rows``, ``bucket``, and for token levels the non-pad ``tokens``
+out of ``token_slots``), ``ocl.wait`` (each host block on a device
+result), ``ocl.expert`` (annotation submit and label resolve),
+``ocl.commit``, ``ocl.sample`` (cache-index draws and their puts) and
+``ocl.update`` (one update program's dispatch, ``step`` named as its
+retrace probe).
 """
 from __future__ import annotations
 
@@ -261,6 +279,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import sanitize as _san
 from repro.core.cascade import CascadeConfig, _Level, make_history
@@ -299,6 +318,15 @@ def lanes_due(k: int, age: int, max_delay: int, per_lane: bool) -> int:
     return (age * k) // max_delay
 
 
+def _count_tokens(span: TraceAnnotation, xb: np.ndarray) -> None:
+    """Put a token level's fill on its ``ocl.route_pass`` span: the
+    non-pad ids of the padded batch (pad is 0 in ``hash_ids``, every real
+    id is >= 1) out of its slots.  Feature levels (float rows) get none."""
+    if np.issubdtype(xb.dtype, np.integer):
+        span.set_metadata(tokens=int(np.count_nonzero(xb)),
+                          token_slots=int(xb.size))
+
+
 @dataclass
 class _PendingTick:
     """One routed tick whose expert annotations are still in flight.
@@ -322,7 +350,7 @@ class _PendingTick:
     lane_cache_rngs: Optional[list] = None   # per called lane, per level
     lanes: Optional[np.ndarray] = None  # physical lane per tick position
                                         # (occupancy ticks; None = arange)
-    wall: float = 0.0             # wall-clock at submit (latency stats)
+    wall: float = 0.0             # perf_counter at submit (latency stats)
     feats_dev: Optional[list] = None   # device copies of feats, uploaded
                                        # once and shared by the record's
                                        # per-lane scatters
@@ -701,12 +729,13 @@ class BatchedCascadeEngine:
         so a successful requeue yields the exact labels the original
         shard would have — fault timing never changes committed state,
         only permanent drops do."""
-        while True:
-            try:
-                return np.asarray(rec.ticket.result_slice(
-                    lo, hi, timeout=self.expert_timeout), np.int32)
-            except ExpertShardError as e:
-                self._requeue_shard(rec, e)
+        with TraceAnnotation("ocl.expert", tick=rec.t, rows=hi - lo):
+            while True:
+                try:
+                    return np.asarray(rec.ticket.result_slice(
+                        lo, hi, timeout=self.expert_timeout), np.int32)
+                except ExpertShardError as e:
+                    self._requeue_shard(rec, e)
 
     def _requeue_shard(self, rec: _PendingTick, err: ExpertShardError):
         k = rec.sel_c.size
@@ -856,21 +885,26 @@ class BatchedCascadeEngine:
             outs.append(self._route_resolve(self._ring.popleft()))
         return outs
 
-    def _dispatch_level(self, i: int, fi: np.ndarray, sel: np.ndarray):
+    def _dispatch_level(self, i: int, fi: np.ndarray, sel: np.ndarray,
+                        t: int, calib: int = 0):
         """Pad the gathered lane subset ``fi[sel]`` to its bucket and
         dispatch the level-i route pass (async — no host sync).
 
         Returns ``(handles, xb)``: the in-flight (probs, dprob) device
         pair and the padded host batch (kept by stage A for refetch).
         Shared by the stage-A dispatch, the stage-B walk, and the
-        every-gate calibration forwards so the pad/bucket/placement rule
-        cannot drift between them."""
+        every-gate calibration forwards (``calib=1``) so the
+        pad/bucket/placement rule cannot drift between them."""
         lvl = self.levels[i]
         B = self._bucket(sel.size)
-        xb = np.zeros((B,) + fi.shape[1:], fi.dtype)
-        xb[:sel.size] = fi[sel]
-        handles = self._predict_defer[i](lvl.params, lvl.dparams,
-                                         self._put_lane(xb))
+        with TraceAnnotation("ocl.route_pass", tick=t, level=i,
+                             rows=sel.size, bucket=B,
+                             calib=calib) as span:
+            xb = np.zeros((B,) + fi.shape[1:], fi.dtype)
+            xb[:sel.size] = fi[sel]
+            _count_tokens(span, xb)
+            handles = self._predict_defer[i](lvl.params, lvl.dparams,
+                                             self._put_lane(xb))
         return handles, xb
 
     def _route_dispatch(self, indices: Sequence[int], docs, *,
@@ -908,79 +942,86 @@ class BatchedCascadeEngine:
             raise ValueError("stream_ticks must have one entry per position")
         self.t += 1
         t = self.t
-        self.pipeline_stats["submitted"] += 1
-        if self.autoscale is not None:
-            self._autoscale_tick()
+        with TraceAnnotation("ocl.route_dispatch", tick=t, lanes=S):
+            self.pipeline_stats["submitted"] += 1
+            if self.autoscale is not None:
+                self._autoscale_tick()
 
-        # lazy per-level featurization: a level's feature batch is only
-        # built if some lane actually reaches it (mirrors the reference's
-        # per-item feat() cache; in a cheap-level-dominant steady state
-        # the expensive levels' featurizers never run)
-        feats_cache: list = [None] * nlev
+            # lazy per-level featurization: a level's feature batch is only
+            # built if some lane actually reaches it (mirrors the reference's
+            # per-item feat() cache; in a cheap-level-dominant steady state
+            # the expensive levels' featurizers never run)
+            feats_cache: list = [None] * nlev
 
-        u_jump = np.empty((nlev, S))
-        u_act = np.empty((nlev, S), np.float32)
-        cache_rngs = None
-        # per-lane commit mode samples each lane's cache mini-batch with
-        # the LANE'S OWN tick generators (the sequential reference's
-        # per-item rule); per-tick mode only needs the lane-0 purpose
-        lane_cache = [] if self.per_lane else None
-        for s in range(S):
-            # a dynamically-admitted stream keeps its OWN (stream id,
-            # local tick) key regardless of which lane or global tick
-            # serves it — this is what makes its per-item draws identical
-            # to the dedicated-lane run (tests/test_admission.py pins it)
-            sid = s if stream_ids is None else int(stream_ids[s])
-            lt = t if stream_ticks is None else int(stream_ticks[s])
-            r = tick_rngs(cfg.seed, sid, lt, nlev)
-            u_jump[:, s] = r.jump.random(nlev)
-            u_act[:, s] = r.action.random(nlev).astype(np.float32)
-            if lane_cache is not None:
-                lane_cache.append(r.cache)
-            if s == 0:
-                cache_rngs = r.cache
+            u_jump = np.empty((nlev, S))
+            u_act = np.empty((nlev, S), np.float32)
+            cache_rngs = None
+            # per-lane commit mode samples each lane's cache mini-batch with
+            # the LANE'S OWN tick generators (the sequential reference's
+            # per-item rule); per-tick mode only needs the lane-0 purpose
+            lane_cache = [] if self.per_lane else None
+            with TraceAnnotation("ocl.draws", tick=t, lanes=S):
+                for s in range(S):
+                    # a dynamically-admitted stream keeps its OWN (stream
+                    # id, local tick) key regardless of which lane or
+                    # global tick serves it — this is what makes its
+                    # per-item draws identical to the dedicated-lane run
+                    # (tests/test_admission.py pins it)
+                    sid = s if stream_ids is None else int(stream_ids[s])
+                    lt = t if stream_ticks is None else int(stream_ticks[s])
+                    r = tick_rngs(cfg.seed, sid, lt, nlev)
+                    u_jump[:, s] = r.jump.random(nlev)
+                    u_act[:, s] = r.action.random(nlev).astype(np.float32)
+                    if lane_cache is not None:
+                        lane_cache.append(r.cache)
+                    if s == 0:
+                        cache_rngs = r.cache
 
-        budget_ok = not self._budget_exhausted()
-        betas = np.array(self._route_beta)[:, None]
-        jump = (u_jump < betas) & budget_ok
+            budget_ok = not self._budget_exhausted()
+            betas = np.array(self._route_beta)[:, None]
+            jump = (u_jump < betas) & budget_ok
 
-        # level 0 is the only forward whose gather mask is known before
-        # any dprob returns (lanes alive there = lanes that didn't jump);
-        # dispatch it without blocking and start the D2H copy of its
-        # outputs so stage B's np.asarray is a wait, not a round trip
-        sel0 = np.flatnonzero(~jump[0])
-        xb0 = None
-        handles = None
-        if sel0.size:
-            fi = np.stack([self.levels[0].featurize(d) for d in docs])
-            feats_cache[0] = fi
-            handles, xb0 = self._dispatch_level(0, fi, sel0)
-            host_prefetch(handles)
+            # level 0 is the only forward whose gather mask is known before
+            # any dprob returns (lanes alive there = lanes that didn't jump);
+            # dispatch it without blocking and start the D2H copy of its
+            # outputs so stage B's np.asarray is a wait, not a round trip
+            sel0 = np.flatnonzero(~jump[0])
+            xb0 = None
+            handles = None
+            if sel0.size:
+                with TraceAnnotation("ocl.featurize", tick=t, level=0,
+                                     rows=S):
+                    fi = np.stack([self.levels[0].featurize(d)
+                                   for d in docs])
+                feats_cache[0] = fi
+                handles, xb0 = self._dispatch_level(0, fi, sel0, t)
+                host_prefetch(handles)
 
-        # beta decays per consumed ITEM (decay^S per tick): the students
-        # are shared across lanes, so the DAgger exploration budget is
-        # measured in demonstrations seen, matching the reference's
-        # schedule in item-space (identical at S == 1).  The
-        # re-exploration floor (core.deferral) is applied once per tick
-        # at the post-tick item count.  The recurrence is deterministic
-        # in items seen, so it advances HERE, at dispatch (tick sizes
-        # are known) — ``lvl.beta`` is synced to the same value when the
-        # tick resolves, keeping the observable state identical to the
-        # unpipelined engine without a second copy of the schedule.
-        self._route_items += S
-        for i, lvl in enumerate(self.levels):
-            self._route_beta[i] = max(
-                self._route_beta[i] * lvl.spec.beta_decay ** S,
-                reexploration_floor(lvl.spec.beta_floor, self._route_items))
+            # beta decays per consumed ITEM (decay^S per tick): the students
+            # are shared across lanes, so the DAgger exploration budget is
+            # measured in demonstrations seen, matching the reference's
+            # schedule in item-space (identical at S == 1).  The
+            # re-exploration floor (core.deferral) is applied once per tick
+            # at the post-tick item count.  The recurrence is deterministic
+            # in items seen, so it advances HERE, at dispatch (tick sizes
+            # are known) — ``lvl.beta`` is synced to the same value when the
+            # tick resolves, keeping the observable state identical to the
+            # unpipelined engine without a second copy of the schedule.
+            self._route_items += S
+            for i, lvl in enumerate(self.levels):
+                self._route_beta[i] = max(
+                    self._route_beta[i] * lvl.spec.beta_decay ** S,
+                    reexploration_floor(lvl.spec.beta_floor,
+                                        self._route_items))
 
-        return _InFlightTick(
-            t=t, indices=[int(i) for i in indices], docs=list(docs), S=S,
-            jump=jump, u_act=u_act, budget_ok=budget_ok,
-            cache_rngs=cache_rngs, feats_cache=feats_cache, sel0=sel0,
-            xb0=xb0, handles=handles, version=self._state_version,
-            beta_after=list(self._route_beta), lane_cache=lane_cache,
-            lanes=lanes,
-            u_jump_raw=u_jump if _san.determinism_on() else None)
+            return _InFlightTick(
+                t=t, indices=[int(i) for i in indices], docs=list(docs), S=S,
+                jump=jump, u_act=u_act, budget_ok=budget_ok,
+                cache_rngs=cache_rngs, feats_cache=feats_cache, sel0=sel0,
+                xb0=xb0, handles=handles, version=self._state_version,
+                beta_after=list(self._route_beta), lane_cache=lane_cache,
+                lanes=lanes,
+                u_jump_raw=u_jump if _san.determinism_on() else None)
 
     def _route_resolve(self, rec: _InFlightTick) -> dict:
         """Stage B: host routing, expert submit, commits, accounting.
@@ -989,237 +1030,257 @@ class BatchedCascadeEngine:
         exactly, in FIFO tick order; the only pipelined difference is
         that the level-0 forward was dispatched earlier (and is refetched
         here if a commit landed since)."""
-        cfg = self.cfg
-        nlev = len(self.levels)
-        S = rec.S
-        t = rec.t
-        docs = rec.docs
-        u_act = rec.u_act
-        jump = rec.jump
-        budget_ok = rec.budget_ok
-        cache_rngs = rec.cache_rngs
-        feats_cache = rec.feats_cache
-        self.pipeline_stats["resolved"] += 1
+        with TraceAnnotation("ocl.route_resolve", tick=rec.t,
+                             lanes=rec.S) as span:
+            cfg = self.cfg
+            nlev = len(self.levels)
+            S = rec.S
+            t = rec.t
+            docs = rec.docs
+            u_act = rec.u_act
+            jump = rec.jump
+            budget_ok = rec.budget_ok
+            cache_rngs = rec.cache_rngs
+            feats_cache = rec.feats_cache
+            self.pipeline_stats["resolved"] += 1
 
-        def feats(i):
-            if feats_cache[i] is None:
-                feats_cache[i] = np.stack(
-                    [self.levels[i].featurize(d) for d in docs])
-            return feats_cache[i]
+            def feats(i):
+                if feats_cache[i] is None:
+                    with TraceAnnotation("ocl.featurize", tick=t, level=i,
+                                         rows=S):
+                        feats_cache[i] = np.stack(
+                            [self.levels[i].featurize(d) for d in docs])
+                return feats_cache[i]
 
-        handles = rec.handles
-        if handles is not None and rec.version != self._state_version:
-            # a commit landed after this tick's dispatch: the speculated
-            # level-0 forward read pre-update params.  Refetch against
-            # the committed state (featurization is parameter-independent
-            # and is reused; only the jitted forward re-runs)
-            self.pipeline_stats["refetches"] += 1
-            lvl = self.levels[0]
-            handles = self._predict_defer[0](
-                lvl.params, lvl.dparams, self._put_lane(rec.xb0))
+            handles = rec.handles
+            if handles is not None and rec.version != self._state_version:
+                # a commit landed after this tick's dispatch: the
+                # speculated level-0 forward read pre-update params.
+                # Refetch against the committed state (featurization is
+                # parameter-independent and is reused; only the jitted
+                # forward re-runs)
+                self.pipeline_stats["refetches"] += 1
+                lvl = self.levels[0]
+                with TraceAnnotation("ocl.route_pass", tick=t, level=0,
+                                     rows=rec.sel0.size,
+                                     bucket=rec.xb0.shape[0],
+                                     calib=0) as pass_span:
+                    _count_tokens(pass_span, rec.xb0)
+                    handles = self._predict_defer[0](
+                        lvl.params, lvl.dparams, self._put_lane(rec.xb0))
 
-        # -- vectorized cascade walk: one gathered, batched predict+defer
-        #    call per level over the lanes still alive there --------------
-        alive = np.ones(S, bool)            # walking, not yet exited
-        jumped = np.zeros(S, bool)
-        eval_mask = np.zeros((nlev, S), bool)
-        dprob_h = np.zeros((nlev, S), np.float32)
-        probs_h = np.zeros((nlev, S, cfg.n_classes), np.float32)
-        predictions = np.zeros(S, np.int64)
-        exit_level = np.full(S, nlev, np.int64)   # nlev = reached expert
-        for i, lvl in enumerate(self.levels):
-            jump_now = alive & jump[i]
-            jumped |= jump_now
-            alive &= ~jump[i]
-            sel = np.flatnonzero(alive)
-            if sel.size == 0:
-                continue
-            if i == 0:
-                # pre-dispatched at stage A (sel == rec.sel0 by
-                # construction: the jump mask is identical)
-                probs_d, dprob_d = handles
-            else:
-                (probs_d, dprob_d), _ = self._dispatch_level(i, feats(i),
-                                                             sel)
-            probs_np = np.asarray(probs_d)[:sel.size]
-            dprob_np = np.asarray(dprob_d)[:sel.size]
-            eval_mask[i, sel] = True
-            dprob_h[i, sel] = dprob_np
-            probs_h[i, sel] = probs_np
-            if cfg.sample_actions:
-                defer_np = u_act[i, sel] < dprob_np
-            else:
-                defer_np = dprob_np > 0.5
-            if not budget_ok and i == nlev - 1:
-                defer_np[:] = False     # budget gate: cannot reach expert
-            take = sel[~defer_np]
-            predictions[take] = np.argmax(probs_np[~defer_np], axis=-1)
-            exit_level[take] = i
-            alive[take] = False
-
-        want = jumped | alive               # deferred past the last level
-        level_costs = np.array([lvl.spec.cost for lvl in self.levels])
-        cost_h = eval_mask.T @ level_costs  # sum of evaluated level costs
-
-        # hard budget at tick granularity: first `remaining` lanes win
-        called = want.copy()
-        hb = cfg.hard_budget
-        if hb is not None:
-            remaining = max(hb - self.expert_calls_total, 0)
-            if int(called.sum()) > remaining:
-                idx_want = np.flatnonzero(called)
-                called[idx_want[remaining:]] = False
-        overflow = want & ~called
-
-        for s in np.flatnonzero(overflow):
-            # budget overflow: fall back to the last student, like the
-            # reference's exhausted-budget path (rare; never at S == 1).
-            # The fallback forward is real compute and is costed as an
-            # evaluation of the last level, identically to the
-            # sequential reference; the lane is counted as a last-level
-            # exit even if it jumped earlier
-            lvl = self.levels[-1]
-            probs = np.asarray(lvl._predict(
-                lvl.params, jnp.asarray(feats(nlev - 1)[s])))
-            predictions[s] = int(np.argmax(probs))
-
-        levels_out = np.where(called, nlev,
-                              np.where(overflow, nlev - 1, exit_level))
-        cost_out = (cost_h + np.where(called, cfg.expert_cost, 0.0)
-                    + np.where(overflow, self.levels[-1].spec.cost, 0.0))
-
-        y_full = np.zeros(S, np.int32)
-        resolved = False
-        prec = None
-        if called.any():
-            sel_c = np.flatnonzero(called)
-
-            # the update only reads the called lanes' rows (others are
-            # dropped by the scatter), so for levels the route never
-            # featurized, hash just those k docs instead of all S
-            def scatter_feats(i):
-                if feats_cache[i] is not None:
-                    return feats_cache[i]
-                lvl = self.levels[i]
-                arr = np.zeros((S,) + lvl.cache_x.shape[1:],
-                               lvl.cache_x.dtype)
-                for s in sel_c:
-                    arr[s] = lvl.featurize(docs[s])
-                feats_cache[i] = arr
-                return arr
-
-            # every annotated lane calibrates EVERY gate (core.deferral):
-            # levels the route never evaluated for a called lane (DAgger
-            # jumps short-circuit the walk) get probs/dprob computed at
-            # route time against the tick's pre-update students — the
-            # same values the synchronous engine computes after its
-            # expert call (no update can land in between), and what the
-            # deferred lanes' provisional predictions read from
+            # -- vectorized cascade walk: one gathered, batched
+            #    predict+defer call per level over the lanes still alive
+            #    there -------------------------------------------------------
+            alive = np.ones(S, bool)            # walking, not yet exited
+            jumped = np.zeros(S, bool)
+            eval_mask = np.zeros((nlev, S), bool)
+            dprob_h = np.zeros((nlev, S), np.float32)
+            probs_h = np.zeros((nlev, S, cfg.n_classes), np.float32)
+            predictions = np.zeros(S, np.int64)
+            exit_level = np.full(S, nlev, np.int64)   # nlev = reached expert
             for i, lvl in enumerate(self.levels):
-                missing = np.flatnonzero(called & ~eval_mask[i])
-                if missing.size == 0:
+                jump_now = alive & jump[i]
+                jumped |= jump_now
+                alive &= ~jump[i]
+                sel = np.flatnonzero(alive)
+                if sel.size == 0:
                     continue
-                (probs_d, dprob_d), _ = self._dispatch_level(
-                    i, scatter_feats(i), missing)
-                probs_h[i, missing] = np.asarray(probs_d)[:missing.size]
-                dprob_h[i, missing] = np.asarray(dprob_d)[:missing.size]
+                if i == 0:
+                    # pre-dispatched at stage A (sel == rec.sel0 by
+                    # construction: the jump mask is identical)
+                    probs_d, dprob_d = handles
+                else:
+                    (probs_d, dprob_d), _ = self._dispatch_level(
+                        i, feats(i), sel, t)
+                with TraceAnnotation("ocl.wait", tick=t, level=i):
+                    probs_np = np.asarray(probs_d)[:sel.size]
+                    dprob_np = np.asarray(dprob_d)[:sel.size]
+                eval_mask[i, sel] = True
+                dprob_h[i, sel] = dprob_np
+                probs_h[i, sel] = probs_np
+                if cfg.sample_actions:
+                    defer_np = u_act[i, sel] < dprob_np
+                else:
+                    defer_np = dprob_np > 0.5
+                if not budget_ok and i == nlev - 1:
+                    defer_np[:] = False     # budget gate: cannot reach expert
+                take = sel[~defer_np]
+                predictions[take] = np.argmax(probs_np[~defer_np], axis=-1)
+                exit_level[take] = i
+                alive[take] = False
 
-            idxs_c = [rec.indices[s] for s in sel_c]
-            docs_c = [docs[s] for s in sel_c]
-            ticket = self._expert_submit(idxs_c, docs_c)
-            prec = _PendingTick(
-                ticket=ticket, t=t, called=called.copy(), sel_c=sel_c,
-                feats=[scatter_feats(i) for i in range(nlev)],
-                probs=probs_h, dprob=dprob_h, cache_rngs=cache_rngs,
-                lane_cache_rngs=(
-                    [rec.lane_cache[s] for s in sel_c]
-                    if self.per_lane else None),
-                lanes=rec.lanes,
-                wall=time.time(),
-                idxs=idxs_c, docs_k=docs_c)
-            if self.max_delay == 0:
-                # synchronous path: resolve inline — with the identical
-                # op sequence as ever (bitwise parity contract).  The
-                # requeue-aware resolve means a fault here heals or
-                # degrades exactly like a deferred commit would; -1
-                # marks an annotation dropped past max_requeues, whose
-                # lane keeps the last student's provisional answer
-                y_lab = self._resolve_labels(prec, 0, sel_c.size)
-                y_full[sel_c] = y_lab
-                predictions[sel_c] = np.where(
-                    y_lab >= 0, y_lab,
-                    np.argmax(probs_h[nlev - 1, sel_c], axis=-1))
-                resolved = True
-            else:
-                # deferred lanes emit the LAST student's prediction
-                # provisionally; the annotation lands max_delay ticks
-                # later.  The probs are the route-time calibration
-                # forwards — no extra serving compute
-                predictions[sel_c] = np.argmax(
-                    probs_h[nlev - 1, sel_c], axis=-1)
+            want = jumped | alive               # deferred past the last level
+            level_costs = np.array([lvl.spec.cost for lvl in self.levels])
+            cost_h = eval_mask.T @ level_costs  # sum of evaluated level costs
 
-        if prec is not None:
-            self._pending.append(prec)
-        # bounded annotation delay, measured in TICKS (not in
-        # expert-calling ticks): a record routed at tick u commits at the
-        # end of tick u + max_delay even if no intervening tick called
-        # the expert — otherwise the converged regime's trickle
-        # annotations (the PR-2 beta-floor calibration signal) could be
-        # starved for arbitrarily many ticks.  Blocks on the expert if it
-        # is slower than max_delay ticks of student compute —
-        # deterministic for any expert latency.  Per-lane mode drains on
-        # the finer lanes_due sub-deadline schedule instead of whole
-        # ticks at age D (see _drain_due).
-        self._drain_due(t)
+            # hard budget at tick granularity: first `remaining` lanes win
+            called = want.copy()
+            hb = cfg.hard_budget
+            if hb is not None:
+                remaining = max(hb - self.expert_calls_total, 0)
+                if int(called.sum()) > remaining:
+                    idx_want = np.flatnonzero(called)
+                    called[idx_want[remaining:]] = False
+            overflow = want & ~called
+            span.set_metadata(called=int(called.sum()))
 
-        # sync the observable beta to the value the dispatch-time
-        # recurrence produced for this tick (see _route_dispatch — one
-        # schedule, computed once)
-        for lvl, b in zip(self.levels, rec.beta_after):
-            lvl.beta = b
+            for s in np.flatnonzero(overflow):
+                # budget overflow: fall back to the last student, like the
+                # reference's exhausted-budget path (rare; never at S == 1).
+                # The fallback forward is real compute and is costed as an
+                # evaluation of the last level, identically to the
+                # sequential reference; the lane is counted as a last-level
+                # exit even if it jumped earlier
+                lvl = self.levels[-1]
+                x = jnp.asarray(feats(nlev - 1)[s])
+                with TraceAnnotation("ocl.wait", tick=t, level=nlev - 1):
+                    probs = np.asarray(lvl._predict(lvl.params, x))
+                predictions[s] = int(np.argmax(probs))
 
-        # per-stream accounting, at the physical lanes this tick occupied
-        lanes = np.arange(S) if rec.lanes is None else rec.lanes
-        J_t = cfg.mu * cost_out
-        self.expert_calls[lanes] += called.astype(np.int64)
-        self.total_cost[lanes] += cost_out
-        self.level_counts[lanes, levels_out] += 1
-        self.items_seen[lanes] += 1
-        self.J_cum[lanes] += J_t
-        if self.history is not None:
-            self.history["level"].append(levels_out.copy())
-            self.history["pred"].append(predictions.astype(np.int64))
-            self.history["expert_called"].append(called.copy())
-            self.history["cost"].append(cost_out.copy())
-            self.history["J"].append(J_t.copy())
-        if _san.determinism_on() and rec.u_jump_raw is not None:
-            # determinism-sanitizer trace: one record per resolved tick,
-            # after this tick's due commits — a deterministic point of
-            # the schedule, so traces from any worker count / pipeline
-            # depth / mesh placement are comparable tick-by-tick
-            _san.record_tick(
-                self, t=t, level=levels_out, called=called,
-                pred=predictions, u_jump=rec.u_jump_raw, u_act=u_act,
-                cache_n=self._cache_n, cache_ptr=self._cache_ptr,
-                levels=self.levels)
-        return {
-            # which stream items this tick served (pipelined callers map
-            # late-resolving outputs back to their submission)
-            "indices": np.asarray(rec.indices, np.int64),
-            "tick": t,
-            # physical lane per position (the occupancy identity when the
-            # tick was submitted without lanes=)
-            "lanes": lanes.copy(),
-            "predictions": predictions.astype(np.int64),
-            "levels": levels_out,
-            "expert_called": called,
-            "cost_units": cost_out,
-            # annotations still in flight (max_delay >= 1) report -1;
-            # they land at commit time, never in a tick's output
-            "expert_labels": (np.where(called, y_full,
-                                       np.int32(-1)).astype(np.int32)
-                              if resolved else np.full(S, -1, np.int32)),
-        }
+            levels_out = np.where(called, nlev,
+                                  np.where(overflow, nlev - 1, exit_level))
+            cost_out = (cost_h + np.where(called, cfg.expert_cost, 0.0)
+                        + np.where(overflow, self.levels[-1].spec.cost, 0.0))
+
+            y_full = np.zeros(S, np.int32)
+            resolved = False
+            prec = None
+            if called.any():
+                sel_c = np.flatnonzero(called)
+
+                # the update only reads the called lanes' rows (others are
+                # dropped by the scatter), so for levels the route never
+                # featurized, hash just those k docs instead of all S
+                def scatter_feats(i):
+                    if feats_cache[i] is not None:
+                        return feats_cache[i]
+                    lvl = self.levels[i]
+                    arr = np.zeros((S,) + lvl.cache_x.shape[1:],
+                                   lvl.cache_x.dtype)
+                    with TraceAnnotation("ocl.featurize", tick=t, level=i,
+                                         rows=sel_c.size):
+                        for s in sel_c:
+                            arr[s] = lvl.featurize(docs[s])
+                    feats_cache[i] = arr
+                    return arr
+
+                # every annotated lane calibrates EVERY gate
+                # (core.deferral): levels the route never evaluated for a
+                # called lane (DAgger jumps short-circuit the walk) get
+                # probs/dprob computed at route time against the tick's
+                # pre-update students — the same values the synchronous
+                # engine computes after its expert call (no update can land
+                # in between), and what the deferred lanes' provisional
+                # predictions read from
+                for i, lvl in enumerate(self.levels):
+                    missing = np.flatnonzero(called & ~eval_mask[i])
+                    if missing.size == 0:
+                        continue
+                    (probs_d, dprob_d), _ = self._dispatch_level(
+                        i, scatter_feats(i), missing, t, calib=1)
+                    n_m = missing.size
+                    with TraceAnnotation("ocl.wait", tick=t, level=i):
+                        probs_h[i, missing] = np.asarray(probs_d)[:n_m]
+                        dprob_h[i, missing] = np.asarray(dprob_d)[:n_m]
+
+                idxs_c = [rec.indices[s] for s in sel_c]
+                docs_c = [docs[s] for s in sel_c]
+                with TraceAnnotation("ocl.expert", tick=t, rows=sel_c.size):
+                    ticket = self._expert_submit(idxs_c, docs_c)
+                prec = _PendingTick(
+                    ticket=ticket, t=t, called=called.copy(), sel_c=sel_c,
+                    feats=[scatter_feats(i) for i in range(nlev)],
+                    probs=probs_h, dprob=dprob_h, cache_rngs=cache_rngs,
+                    lane_cache_rngs=(
+                        [rec.lane_cache[s] for s in sel_c]
+                        if self.per_lane else None),
+                    lanes=rec.lanes,
+                    wall=time.perf_counter(),
+                    idxs=idxs_c, docs_k=docs_c)
+                if self.max_delay == 0:
+                    # synchronous path: resolve inline — with the identical
+                    # op sequence as ever (bitwise parity contract).  The
+                    # requeue-aware resolve means a fault here heals or
+                    # degrades exactly like a deferred commit would; -1
+                    # marks an annotation dropped past max_requeues, whose
+                    # lane keeps the last student's provisional answer
+                    y_lab = self._resolve_labels(prec, 0, sel_c.size)
+                    y_full[sel_c] = y_lab
+                    predictions[sel_c] = np.where(
+                        y_lab >= 0, y_lab,
+                        np.argmax(probs_h[nlev - 1, sel_c], axis=-1))
+                    resolved = True
+                else:
+                    # deferred lanes emit the LAST student's prediction
+                    # provisionally; the annotation lands max_delay ticks
+                    # later.  The probs are the route-time calibration
+                    # forwards — no extra serving compute
+                    predictions[sel_c] = np.argmax(
+                        probs_h[nlev - 1, sel_c], axis=-1)
+
+            if prec is not None:
+                self._pending.append(prec)
+            # bounded annotation delay, measured in TICKS (not in
+            # expert-calling ticks): a record routed at tick u commits at the
+            # end of tick u + max_delay even if no intervening tick called
+            # the expert — otherwise the converged regime's trickle
+            # annotations (the PR-2 beta-floor calibration signal) could be
+            # starved for arbitrarily many ticks.  Blocks on the expert if it
+            # is slower than max_delay ticks of student compute —
+            # deterministic for any expert latency.  Per-lane mode drains on
+            # the finer lanes_due sub-deadline schedule instead of whole
+            # ticks at age D (see _drain_due).
+            self._drain_due(t)
+
+            # sync the observable beta to the value the dispatch-time
+            # recurrence produced for this tick (see _route_dispatch — one
+            # schedule, computed once)
+            for lvl, b in zip(self.levels, rec.beta_after):
+                lvl.beta = b
+
+            # per-stream accounting, at the physical lanes this tick occupied
+            lanes = np.arange(S) if rec.lanes is None else rec.lanes
+            J_t = cfg.mu * cost_out
+            self.expert_calls[lanes] += called.astype(np.int64)
+            self.total_cost[lanes] += cost_out
+            self.level_counts[lanes, levels_out] += 1
+            self.items_seen[lanes] += 1
+            self.J_cum[lanes] += J_t
+            if self.history is not None:
+                self.history["level"].append(levels_out.copy())
+                self.history["pred"].append(predictions.astype(np.int64))
+                self.history["expert_called"].append(called.copy())
+                self.history["cost"].append(cost_out.copy())
+                self.history["J"].append(J_t.copy())
+            if _san.determinism_on() and rec.u_jump_raw is not None:
+                # determinism-sanitizer trace: one record per resolved tick,
+                # after this tick's due commits — a deterministic point of
+                # the schedule, so traces from any worker count / pipeline
+                # depth / mesh placement are comparable tick-by-tick
+                _san.record_tick(
+                    self, t=t, level=levels_out, called=called,
+                    pred=predictions, u_jump=rec.u_jump_raw, u_act=u_act,
+                    cache_n=self._cache_n, cache_ptr=self._cache_ptr,
+                    levels=self.levels)
+            return {
+                # which stream items this tick served (pipelined callers map
+                # late-resolving outputs back to their submission)
+                "indices": np.asarray(rec.indices, np.int64),
+                "tick": t,
+                # physical lane per position (the occupancy identity when the
+                # tick was submitted without lanes=)
+                "lanes": lanes.copy(),
+                "predictions": predictions.astype(np.int64),
+                "levels": levels_out,
+                "expert_called": called,
+                "cost_units": cost_out,
+                # annotations still in flight (max_delay >= 1) report -1;
+                # they land at commit time, never in a tick's output
+                "expert_labels": (np.where(called, y_full,
+                                           np.int32(-1)).astype(np.int32)
+                                  if resolved else np.full(S, -1, np.int32)),
+            }
 
     # -- commit: apply routed ticks' landed annotations ------------------
     def _drain_due(self, t: int) -> None:
@@ -1287,7 +1348,8 @@ class BatchedCascadeEngine:
         self.commit_stats["age_sum"] += n * (t - rec.t)
         self.commit_stats["age_max"] = max(self.commit_stats["age_max"],
                                            t - rec.t)
-        self.commit_stats["wall_sum"] += n * (time.time() - rec.wall)
+        self.commit_stats["wall_sum"] += n * (time.perf_counter()
+                                              - rec.wall)
         if self.commit_log is not None:
             if rec.lanes is None:
                 self.commit_log.extend((rec.t, int(s), t) for s in lanes)
@@ -1300,80 +1362,92 @@ class BatchedCascadeEngine:
         plus the per-tick weighted student/deferral updates, exactly the
         synchronous engine's update block replayed in FIFO tick order
         with the tick's own cache-sampling generators."""
-        cfg = self.cfg
-        nlev = len(self.levels)
-        sel_c = rec.sel_c
-        k = sel_c.size
-        y_sel = self._resolve_labels(rec, 0, k)
-        # -1 marks annotations dropped after max_requeues: those lanes
-        # contribute no demonstration — no cache insert, zero update
-        # weight, no commit record (the drop was already counted in
-        # fault_stats at force-resolve time).  In a fault-free run
-        # ok is all-True and this block is bitwise the original path.
-        ok = y_sel >= 0
-        k_ok = int(ok.sum())
-        if k_ok == 0:
+        at = self.t if t is None else t
+        with TraceAnnotation("ocl.commit", tick=rec.t, at=at) as span:
+            cfg = self.cfg
+            nlev = len(self.levels)
+            sel_c = rec.sel_c
+            k = sel_c.size
+            y_sel = self._resolve_labels(rec, 0, k)
+            # -1 marks annotations dropped after max_requeues: those lanes
+            # contribute no demonstration — no cache insert, zero update
+            # weight, no commit record (the drop was already counted in
+            # fault_stats at force-resolve time).  In a fault-free run
+            # ok is all-True and this block is bitwise the original path.
+            ok = y_sel >= 0
+            k_ok = int(ok.sum())
+            span.set_metadata(rows=k_ok)
+            if k_ok == 0:
+                rec.committed = k
+                return
+            called_eff = rec.called
+            if k_ok < k:
+                called_eff = rec.called.copy()
+                called_eff[sel_c[~ok]] = False
+            S = rec.called.shape[0]
+            y_full = np.zeros(S, np.int32)
+            y_full[sel_c] = np.maximum(y_sel, 0)
+
+            # host mirrors first: sampling sees the post-insert fill level
+            ptr_pre = np.asarray(self._cache_ptr, np.int32)
+            idx_t = []
+            for i, lvl in enumerate(self.levels):
+                size = lvl.spec.cache_size
+                self._cache_n[i] = min(self._cache_n[i] + k_ok, size)
+                self._cache_ptr[i] = (self._cache_ptr[i] + k_ok) % size
+                with TraceAnnotation("ocl.sample", tick=rec.t, level=i):
+                    idx_t.append(jnp.asarray(sample_cache_indices(
+                        rec.cache_rngs[i], self._cache_n[i],
+                        self._bs_list[i]).astype(np.int32)))
+
+            with TraceAnnotation("ocl.update", tick=rec.t,
+                                 step="cache_scatter"):
+                new_cx, new_cy = self._scatter(
+                    tuple(self._cache_x), tuple(self._cache_y),
+                    tuple(self._put_lane(rec.feats[i]) for i in range(nlev)),
+                    self._put_lane(y_full), self._put_lane(called_eff),
+                    jnp.asarray(ptr_pre))
+            self._cache_x = list(new_cx)
+            self._cache_y = list(new_cy)
+            # batched, per-item-weighted updates through the SAME jitted
+            # step callables as the sequential reference (bit-identical
+            # state evolution; see module docstring)
+            # reach[l] = prod_{k<l} dprob[k], float32 left fold like the
+            # reference's running product
+            reach = np.ones((nlev, S), np.float32)
+            for i in range(1, nlev):
+                reach[i] = reach[i - 1] * rec.dprob[i - 1]
+            scaled = self.updates_per_tick == "scaled" and k_ok > 1
+            k_arr = jnp.asarray(float(k_ok), jnp.float32) if scaled else None
+            suffix = "_k" if scaled else ""
+            B_c = self._bucket(k)
+            for i, lvl in enumerate(self.levels):
+                kind = lvl.spec.kind
+                with TraceAnnotation("ocl.update", tick=rec.t, level=i,
+                                     step=f"{kind}.student_step{suffix}"):
+                    xb = self._cache_x[i][idx_t[i]]
+                    yb = self._cache_y[i][idx_t[i]]
+                    w = jnp.ones((self._bs_list[i],), jnp.float32)
+                    lvl.apply_student_update(xb, yb, w, k_arr)
+                with TraceAnnotation("ocl.update", tick=rec.t, level=i,
+                                     step=f"{kind}.deferral_step{suffix}"):
+                    probs_b = np.zeros((B_c, cfg.n_classes), np.float32)
+                    probs_b[:k] = rec.probs[i, sel_c]
+                    y_b = np.zeros(B_c, np.int32)
+                    y_b[:k] = np.maximum(y_sel, 0)
+                    reach_b = np.zeros(B_c, np.float32)
+                    reach_b[:k] = reach[i, sel_c]
+                    w_b = np.zeros(B_c, np.float32)
+                    w_b[:k] = ok.astype(np.float32)
+                    lvl.apply_deferral_update(
+                        self._put_lane(probs_b), self._put_lane(y_b),
+                        self._put_lane(reach_b), self._put_lane(w_b), k_arr)
             rec.committed = k
-            return
-        called_eff = rec.called
-        if k_ok < k:
-            called_eff = rec.called.copy()
-            called_eff[sel_c[~ok]] = False
-        S = rec.called.shape[0]
-        y_full = np.zeros(S, np.int32)
-        y_full[sel_c] = np.maximum(y_sel, 0)
-
-        # host mirrors first: sampling sees the post-insert fill level
-        ptr_pre = np.asarray(self._cache_ptr, np.int32)
-        idx_t = []
-        for i, lvl in enumerate(self.levels):
-            size = lvl.spec.cache_size
-            self._cache_n[i] = min(self._cache_n[i] + k_ok, size)
-            self._cache_ptr[i] = (self._cache_ptr[i] + k_ok) % size
-            idx_t.append(jnp.asarray(sample_cache_indices(
-                rec.cache_rngs[i], self._cache_n[i],
-                self._bs_list[i]).astype(np.int32)))
-
-        new_cx, new_cy = self._scatter(
-            tuple(self._cache_x), tuple(self._cache_y),
-            tuple(self._put_lane(rec.feats[i]) for i in range(nlev)),
-            self._put_lane(y_full), self._put_lane(called_eff),
-            jnp.asarray(ptr_pre))
-        self._cache_x = list(new_cx)
-        self._cache_y = list(new_cy)
-        # batched, per-item-weighted updates through the SAME jitted
-        # step callables as the sequential reference (bit-identical
-        # state evolution; see module docstring)
-        # reach[l] = prod_{k<l} dprob[k], float32 left fold like the
-        # reference's running product
-        reach = np.ones((nlev, S), np.float32)
-        for i in range(1, nlev):
-            reach[i] = reach[i - 1] * rec.dprob[i - 1]
-        k_arr = (jnp.asarray(float(k_ok), jnp.float32)
-                 if self.updates_per_tick == "scaled" and k_ok > 1 else None)
-        B_c = self._bucket(k)
-        for i, lvl in enumerate(self.levels):
-            xb = self._cache_x[i][idx_t[i]]
-            yb = self._cache_y[i][idx_t[i]]
-            w = jnp.ones((self._bs_list[i],), jnp.float32)
-            lvl.apply_student_update(xb, yb, w, k_arr)
-            probs_b = np.zeros((B_c, cfg.n_classes), np.float32)
-            probs_b[:k] = rec.probs[i, sel_c]
-            y_b = np.zeros(B_c, np.int32)
-            y_b[:k] = np.maximum(y_sel, 0)
-            reach_b = np.zeros(B_c, np.float32)
-            reach_b[:k] = reach[i, sel_c]
-            w_b = np.zeros(B_c, np.float32)
-            w_b[:k] = ok.astype(np.float32)
-            lvl.apply_deferral_update(
-                self._put_lane(probs_b), self._put_lane(y_b),
-                self._put_lane(reach_b), self._put_lane(w_b), k_arr)
-        rec.committed = k
-        self._record_commit(rec, sel_c[ok], self.t if t is None else t)
-        # params/dparams changed: any route forward dispatched before
-        # this commit is stale (the pipeline's resolve checks and
-        # refetches against the new state)
-        self._state_version += 1
+            self._record_commit(rec, sel_c[ok], at)
+            # params/dparams changed: any route forward dispatched before
+            # this commit is stale (the pipeline's resolve checks and
+            # refetches against the new state)
+            self._state_version += 1
 
     def _commit_lane(self, rec: _PendingTick, j: int, t: int) -> None:
         """Apply ONE lane's landed annotation (per-lane commit mode).
@@ -1387,67 +1461,78 @@ class BatchedCascadeEngine:
         only on the ticket shard holding item ``j`` (``result_slice``);
         earlier lanes of the record have already committed (the drain
         advances ``committed`` strictly in lane order)."""
-        cfg = self.cfg
-        nlev = len(self.levels)
-        s = int(rec.sel_c[j])
-        y = self._resolve_labels(rec, j, j + 1)
-        if y[0] < 0:
-            # annotation dropped past max_requeues: no demonstration to
-            # apply — just advance the cursor (the drop was counted in
-            # fault_stats; no commit record, no state change)
+        with TraceAnnotation("ocl.commit", tick=rec.t, at=t) as span:
+            cfg = self.cfg
+            nlev = len(self.levels)
+            s = int(rec.sel_c[j])
+            y = self._resolve_labels(rec, j, j + 1)
+            span.set_metadata(rows=int(y[0] >= 0))
+            if y[0] < 0:
+                # annotation dropped past max_requeues: no demonstration to
+                # apply — just advance the cursor (the drop was counted in
+                # fault_stats; no commit record, no state change)
+                rec.committed = j + 1
+                return
+            S = rec.called.shape[0]
+            y_full = np.zeros(S, np.int32)
+            y_full[s] = y[0]
+            called_one = np.zeros(S, bool)
+            called_one[s] = True
+            ptr_pre = np.asarray(self._cache_ptr, np.int32)
+            idx_t = []
+            rngs = rec.lane_cache_rngs[j]
+            for i, lvl in enumerate(self.levels):
+                size = lvl.spec.cache_size
+                self._cache_n[i] = min(self._cache_n[i] + 1, size)
+                self._cache_ptr[i] = (self._cache_ptr[i] + 1) % size
+                with TraceAnnotation("ocl.sample", tick=rec.t, level=i):
+                    idx_t.append(jnp.asarray(sample_cache_indices(
+                        rngs[i], self._cache_n[i],
+                        self._bs_list[i]).astype(np.int32)))
+            with TraceAnnotation("ocl.update", tick=rec.t,
+                                 step="cache_scatter"):
+                if rec.feats_dev is None:
+                    # the tick's feature rows are shared by all its
+                    # per-lane scatters — upload once per record, not
+                    # once per lane
+                    rec.feats_dev = [self._put_lane(rec.feats[i])
+                                     for i in range(nlev)]
+                new_cx, new_cy = self._scatter(
+                    tuple(self._cache_x), tuple(self._cache_y),
+                    tuple(rec.feats_dev),
+                    self._put_lane(y_full), self._put_lane(called_one),
+                    jnp.asarray(ptr_pre))
+            self._cache_x = list(new_cx)
+            self._cache_y = list(new_cy)
+            # reach[l] = prod_{k<l} dprob[k] at this lane, float32 left
+            # fold like the reference's running product
+            reach = np.float32(1.0)
+            B_c = self._bucket(1)
+            for i, lvl in enumerate(self.levels):
+                kind = lvl.spec.kind
+                with TraceAnnotation("ocl.update", tick=rec.t, level=i,
+                                     step=f"{kind}.student_step"):
+                    xb = self._cache_x[i][idx_t[i]]
+                    yb = self._cache_y[i][idx_t[i]]
+                    w = jnp.ones((self._bs_list[i],), jnp.float32)
+                    lvl.apply_student_update(xb, yb, w)
+                with TraceAnnotation("ocl.update", tick=rec.t, level=i,
+                                     step=f"{kind}.deferral_step"):
+                    probs_b = np.zeros((B_c, cfg.n_classes), np.float32)
+                    probs_b[0] = rec.probs[i, s]
+                    y_b = np.zeros(B_c, np.int32)
+                    y_b[0] = y[0]
+                    reach_b = np.zeros(B_c, np.float32)
+                    reach_b[0] = reach
+                    w_b = np.zeros(B_c, np.float32)
+                    w_b[0] = 1.0
+                    lvl.apply_deferral_update(
+                        self._put_lane(probs_b), self._put_lane(y_b),
+                        self._put_lane(reach_b), self._put_lane(w_b))
+                reach = np.float32(reach * np.float32(rec.dprob[i, s]))
             rec.committed = j + 1
-            return
-        S = rec.called.shape[0]
-        y_full = np.zeros(S, np.int32)
-        y_full[s] = y[0]
-        called_one = np.zeros(S, bool)
-        called_one[s] = True
-        ptr_pre = np.asarray(self._cache_ptr, np.int32)
-        idx_t = []
-        rngs = rec.lane_cache_rngs[j]
-        for i, lvl in enumerate(self.levels):
-            size = lvl.spec.cache_size
-            self._cache_n[i] = min(self._cache_n[i] + 1, size)
-            self._cache_ptr[i] = (self._cache_ptr[i] + 1) % size
-            idx_t.append(jnp.asarray(sample_cache_indices(
-                rngs[i], self._cache_n[i],
-                self._bs_list[i]).astype(np.int32)))
-        if rec.feats_dev is None:
-            # the tick's feature rows are shared by all its per-lane
-            # scatters — upload once per record, not once per lane
-            rec.feats_dev = [self._put_lane(rec.feats[i])
-                             for i in range(nlev)]
-        new_cx, new_cy = self._scatter(
-            tuple(self._cache_x), tuple(self._cache_y),
-            tuple(rec.feats_dev),
-            self._put_lane(y_full), self._put_lane(called_one),
-            jnp.asarray(ptr_pre))
-        self._cache_x = list(new_cx)
-        self._cache_y = list(new_cy)
-        # reach[l] = prod_{k<l} dprob[k] at this lane, float32 left fold
-        # like the reference's running product
-        reach = np.float32(1.0)
-        B_c = self._bucket(1)
-        for i, lvl in enumerate(self.levels):
-            xb = self._cache_x[i][idx_t[i]]
-            yb = self._cache_y[i][idx_t[i]]
-            w = jnp.ones((self._bs_list[i],), jnp.float32)
-            lvl.apply_student_update(xb, yb, w)
-            probs_b = np.zeros((B_c, cfg.n_classes), np.float32)
-            probs_b[0] = rec.probs[i, s]
-            y_b = np.zeros(B_c, np.int32)
-            y_b[0] = y[0]
-            reach_b = np.zeros(B_c, np.float32)
-            reach_b[0] = reach
-            w_b = np.zeros(B_c, np.float32)
-            w_b[0] = 1.0
-            lvl.apply_deferral_update(
-                self._put_lane(probs_b), self._put_lane(y_b),
-                self._put_lane(reach_b), self._put_lane(w_b))
-            reach = np.float32(reach * np.float32(rec.dprob[i, s]))
-        rec.committed = j + 1
-        self._record_commit(rec, [s], t)
-        self._state_version += 1
+            self._record_commit(rec, [s], t)
+            self._state_version += 1
 
     def flush(self) -> int:
         """Drain the deferred-annotation queue (blocking): apply every
@@ -1639,7 +1724,7 @@ class BatchedCascadeEngine:
                     if pm["lane_cache_rngs"] is not None else None),
                 lanes=(np.asarray(pt["lanes"], np.int64)
                        if pm["has_lanes"] else None),
-                wall=time.time(),
+                wall=time.perf_counter(),
                 idxs=[int(i) for i in np.asarray(pt["idxs"])],
                 docs_k=None,
                 requeues={int(lo): int(n)
@@ -1694,7 +1779,7 @@ class BatchedCascadeEngine:
             preds[idxs] = out["predictions"]
             done = max(done, int(idxs.max()) + 1) if idxs.size else done
 
-        t0 = time.time()
+        t0 = time.perf_counter()
         for start in range(first, n, S):
             stop = min(start + S, n)
             idxs = list(range(start, stop))
@@ -1719,7 +1804,7 @@ class BatchedCascadeEngine:
         for out in self.drain():
             take(out)
         self.flush()
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         labels = stream.labels
         served = n - first
         acc = float(np.mean(preds[first:] == labels[first:]))

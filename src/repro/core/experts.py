@@ -68,6 +68,7 @@ from typing import Callable, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import sanitize as _san
 from repro.data.features import hash_ids
@@ -717,13 +718,21 @@ class ModelExpert:
         return int(jnp.argmax(probs[0]))
 
     def label_batch(self, idxs, docs) -> np.ndarray:
-        """One batched forward for a tick's whole deferred subset."""
+        """One batched forward for a tick's whole deferred subset.
+
+        Its host spans (``ocl.featurize``, ``ocl.wait``, level
+        ``expert``) run on the calling thread — a pool worker under
+        ``submit``/``submit_many`` — and carry no tick: the engine's
+        ``ocl.expert`` span around the same annotation carries it."""
         if len(docs) == 0:
             return np.zeros((0,), np.int32)
-        ids = np.stack([hash_ids(d, self.spec.vocab, self.spec.max_len)
-                        for d in docs])
+        with TraceAnnotation("ocl.featurize", level="expert",
+                             rows=len(docs)):
+            ids = np.stack([hash_ids(d, self.spec.vocab, self.spec.max_len)
+                            for d in docs])
         probs = self._predict(self.params, jnp.asarray(ids))
-        return np.asarray(jnp.argmax(probs, axis=-1), np.int32)
+        with TraceAnnotation("ocl.wait", level="expert"):
+            return np.asarray(jnp.argmax(probs, axis=-1), np.int32)
 
     # -- async interface: shard forwards run on pool threads, so the
     #    expert's host+device time overlaps the engine's next-tick
