@@ -23,7 +23,8 @@ cross-module invariants:
    ``sharding/specs.py``) jits with ``donate_argnums``, any
    ``self``-rooted buffer passed at a donated position of a
    ``self.<attr>(...)`` call site must be reassigned later in the same
-   function.  CAS003 checks donated *locals* against a literal
+   function, and must not be read between the call and that
+   reassignment.  CAS003 checks donated *locals* against a literal
    ``donate_argnums`` in the same file; here the donation annotation
    lives in another module, so the per-file rule is blind to it — this
    is exactly how a stale ``self._cache_x`` read after the scatter
@@ -77,7 +78,8 @@ def _all_exports(tree: ast.Module) -> Optional[Set[str]]:
 def _donating_factories(tree: ast.Module) -> Dict[str, Set[int]]:
     """Factory defs in specs.py whose bodies jit with donate_argnums.
 
-    ``jit_route_pass`` -> {2}, ``jit_cache_scatter`` -> {0, 1}.  The
+    ``jit_route_pass`` -> {2}, ``jit_cache_scatter`` -> {0, 1},
+    ``jit_tick_update`` -> {0, 1, 2}.  The
     donation may be conditional (mesh-gated); callers must satisfy
     deadness unconditionally, so positions are collected from every
     branch.
@@ -253,7 +255,8 @@ class ShardingContractRule(Rule):
                 donated = _self_attr_chain(node.args[pos])
                 if donated is None:
                     continue        # transient value: dies on its own
-                if not self._reassigned_after(fn, node.lineno, donated):
+                rebind = self._rebind_line(fn, node.lineno, donated)
+                if rebind is None:
                     yield Finding(
                         self.id, mod.rel, node.args[pos].lineno,
                         node.args[pos].col_offset,
@@ -262,15 +265,34 @@ class ShardingContractRule(Rule):
                         "sharding/specs.py) but never reassigned in this "
                         "function — the attribute keeps pointing at a "
                         "dead buffer; rebind it from the call's outputs")
+                    continue
+                end = getattr(node, "end_lineno", node.lineno)
+                for read in body:
+                    if (isinstance(read, ast.Attribute)
+                            and isinstance(read.ctx, ast.Load)
+                            and _self_attr_chain(read) == donated
+                            and end < read.lineno <= rebind):
+                        yield Finding(
+                            self.id, mod.rel, read.lineno, read.col_offset,
+                            f"self.{donated} is read after it was passed "
+                            f"at donated position {pos} of "
+                            f"self.{attr}(...) (line {node.lineno}) and "
+                            "before it is rebound — it is a dead buffer "
+                            "there; read the call's outputs instead")
 
     @staticmethod
-    def _reassigned_after(fn, lineno: int, attr: str) -> bool:
+    def _rebind_line(fn, lineno: int, attr: str) -> Optional[int]:
+        """First line after ``lineno`` assigning ``self.<attr>`` (alone
+        or in a tuple target), or None."""
+        lines = []
         for node in ast.walk(fn):
             if isinstance(node, ast.Assign) and node.lineno > lineno:
                 for t in node.targets:
-                    if (isinstance(t, ast.Attribute)
-                            and isinstance(t.value, ast.Name)
-                            and t.value.id == "self"
-                            and t.attr == attr):
-                        return True
-        return False
+                    elts = t.elts if isinstance(t, (ast.Tuple, ast.List)) \
+                        else [t]
+                    if any(isinstance(e, ast.Attribute)
+                           and isinstance(e.value, ast.Name)
+                           and e.value.id == "self" and e.attr == attr
+                           for e in elts):
+                        lines.append(node.lineno)
+        return min(lines, default=None)

@@ -26,25 +26,30 @@ tick:
     * the deferred subset is gathered once and sent to the expert as a
       single batched forward (``label_batch``).
 
-  update pass (per tick, not per item)
+  update pass (per tick, not per item): ONE jitted program per committed
+  tick (``sharding.jit_tick_update``), with the ring buffers and every
+  level's learned state donated, so they are written in place and the
+  host dispatches a single call
     * expert demonstrations are scattered into a vectorized ring buffer
-      per level (the FIFO cache of the reference, as one masked scatter
-      in a jitted step with ``donate_argnums`` so the buffers mutate in
-      place instead of copying);
-    * one weighted student OGD/Adam step per level per tick, sampled from
-      the post-insert ring buffer;
-    * one weighted deferral-MLP step per level per tick, with per-item
-      weights w[s] = 1[expert labeled s and s reached this level], and
-      skipped entirely when no lane has mass — exactly when the reference
-      would not step.
+      per level (the FIFO cache of the reference, as one masked scatter);
+    * each level's mini-batch is gathered from the written ring at the
+      indices the host drew, then one weighted student OGD/Adam step per
+      level;
+    * one weighted deferral-MLP step per level, with per-item weights
+      w[s] = 1[expert labeled s and s reached this level], and skipped
+      entirely when no lane has mass — exactly when the reference would
+      not step.
 
-    The update steps are the *same jitted callables* the reference uses
-    (they are batched and weighted by design), invoked once per tick with
-    the whole lane batch instead of once per item.  Reusing the identical
-    compiled program — rather than re-fusing the update math into one
-    mega-graph — is what makes the S == 1 state evolution bit-identical
-    instead of merely close (XLA re-fusion reassociates reductions at the
-    ~1 ulp level).
+    The steps inside the program are the levels' own update methods
+    (``_Level.apply_student_update``/``apply_deferral_update``, which the
+    reference calls once per item), traced on a copy of each level that
+    holds the program's state; they are batched and weighted by design.
+    The program donates only state it returned itself: state installed
+    from outside (construction, ``reset()``, a restore, a caller's
+    weights) is first copied to private buffers (``commit_stats``
+    counts the programs and the copies).  At S == 1 the state evolution
+    is bit-identical to the reference's separate step programs on the
+    CPU (tests/test_batched.py, tests/test_commit_program.py).
 
 RNG / equivalence contract
 --------------------------
@@ -265,12 +270,15 @@ batch), ``ocl.route_pass`` (pad, put and enqueue one level's forward:
 real ``rows``, ``bucket``, and for token levels the non-pad ``tokens``
 out of ``token_slots``), ``ocl.wait`` (each host block on a device
 result), ``ocl.expert`` (annotation submit and label resolve),
-``ocl.commit``, ``ocl.sample`` (cache-index draws and their puts) and
-``ocl.update`` (one update program's dispatch, ``step`` named as its
-retrace probe).
+``ocl.commit``, ``ocl.sample`` (cache-index draws) and ``ocl.update``
+(one update program's dispatch: the per-tick commit's one program as
+``step="commit"``, a per-lane commit's programs named as their retrace
+probes).
 """
 from __future__ import annotations
 
+import copy
+import math
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -282,13 +290,15 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.analysis import sanitize as _san
-from repro.core.cascade import CascadeConfig, _Level, make_history
+from repro.core.cascade import (STATE_ATTRS, CascadeConfig, _Level,
+                                make_history)
 from repro.core.deferral import reexploration_floor
 from repro.core.experts import (ExpertShardError, ExpertShardTimeout,
                                 ExpertTicket)
 from repro.core.rng import (generator_from_state, generator_state,
                             sample_cache_indices, tick_rngs)
-from repro.sharding import host_prefetch, jit_cache_scatter, jit_route_pass
+from repro.sharding import (host_prefetch, jit_cache_scatter, jit_route_pass,
+                            jit_tick_update)
 
 # autoscale unit: target one worker per this many uncommitted deferred
 # items (clipped into the configured [lo, hi] fleet bounds)
@@ -325,6 +335,41 @@ def _count_tokens(span: TraceAnnotation, xb: np.ndarray) -> None:
     if np.issubdtype(xb.dtype, np.integer):
         span.set_metadata(tokens=int(np.count_nonzero(xb)),
                           token_slots=int(xb.size))
+
+
+def _pack(named) -> Tuple[tuple, tuple]:
+    """A tick's named host arrays as one flat buffer per dtype, and the
+    static layout ``_unpack`` reads them back by inside the program: one
+    host->device put per dtype instead of one per array."""
+    groups: dict = {}
+    layout = []
+    for name, a in named:
+        a = np.asarray(a)
+        groups.setdefault(a.dtype.str, []).append(a.ravel())
+        layout.append((name, a.dtype.str, a.shape))
+    return (tuple(np.concatenate(groups[d]) for d in sorted(groups)),
+            tuple(layout))
+
+
+def _unpack(bufs, layout) -> dict:
+    """``_pack``'s arrays by name, as static slices of its buffers."""
+    dtypes = sorted({d for _, d, _ in layout})
+    at = dict.fromkeys(dtypes, 0)
+    out = {}
+    for name, d, shape in layout:
+        n = math.prod(shape)
+        out[name] = bufs[dtypes.index(d)][at[d]:at[d] + n].reshape(shape)
+        at[d] += n
+    return out
+
+
+def _zero_commit_stats() -> dict:
+    """``commit_stats`` of an engine that has committed nothing: lanes,
+    their age and wall latency sums, the worst age, the per-tick commits
+    run as the one donated update program, and how many of those first
+    copied outside state to private buffers."""
+    return {"lanes": 0, "age_sum": 0, "age_max": 0, "wall_sum": 0.0,
+            "programs": 0, "private_copies": 0}
 
 
 @dataclass
@@ -509,6 +554,9 @@ class BatchedCascadeEngine:
         self._cache_y = [self._put_rep(lvl.cache_y) for lvl in self.levels]
         self._cache_n = [0] * nlev
         self._cache_ptr = [0] * nlev
+        # the levels' learned state as the update program last returned
+        # it: the only state it may donate (``_own_state``)
+        self._owned: Optional[tuple] = None
         self.t = 0
         # per-stream accounting (independent per lane)
         S = n_streams
@@ -532,8 +580,7 @@ class BatchedCascadeEngine:
         # admission front-end needs per-lane commit ticks for its
         # per-stream records while running with history_limit=0
         # (core/admission.py consumes the log with a cursor).
-        self.commit_stats = {"lanes": 0, "age_sum": 0, "age_max": 0,
-                             "wall_sum": 0.0}
+        self.commit_stats = _zero_commit_stats()
         if commit_log is None:
             commit_log = history_limit is None
         self.commit_log: Optional[list] = [] if commit_log else None
@@ -569,6 +616,7 @@ class BatchedCascadeEngine:
         self._cache_y = [self._put_rep(lvl.cache_y) for lvl in self.levels]
         self._cache_n = [0] * nlev
         self._cache_ptr = [0] * nlev
+        self._owned = None
         self.t = 0
         self.expert_calls[:] = 0
         self.total_cost[:] = 0
@@ -587,8 +635,7 @@ class BatchedCascadeEngine:
         self._state_version += 1
         for k in self.pipeline_stats:
             self.pipeline_stats[k] = 0
-        self.commit_stats = {"lanes": 0, "age_sum": 0, "age_max": 0,
-                             "wall_sum": 0.0}
+        self.commit_stats = _zero_commit_stats()
         if self.commit_log is not None:
             self.commit_log.clear()
         for k in self.fault_stats:
@@ -668,6 +715,38 @@ class BatchedCascadeEngine:
         # mode's one-scatter-per-lane cadence (sharding.jit_cache_scatter)
         self._scatter = jit_cache_scatter(
             _san.trace_probe("cache_scatter", scatter), self.mesh)
+
+        def student_step(cx_t, cy_t, state, bufs, layout):
+            """A committed tick's whole update pass: the ring scatter,
+            each level's mini-batch gathered from the written ring at the
+            host-drawn indices, then each level's student and deferral-
+            gate steps.  The steps are the levels' own update methods,
+            traced on a copy of the level that holds this program's
+            state.  (Its XLA program is ``jit_student_step``, the name
+            under which the update pass's device time is read.)"""
+            a = _unpack(bufs, layout)
+            new_cx, new_cy = scatter(
+                cx_t, cy_t, tuple(a[f"feats{i}"] for i in range(nlev)),
+                a["y_full"], a["called"] != 0, a["ptr"])
+            k = a.get("k")
+            new_state = []
+            for i, lvl in enumerate(levels):
+                view = copy.copy(lvl)
+                for attr, v in zip(STATE_ATTRS, state[i]):
+                    setattr(view, attr, v)
+                idx = a[f"idx{i}"]
+                view.apply_student_update(new_cx[i][idx], new_cy[i][idx],
+                                          a[f"w{i}"], k)
+                view.apply_deferral_update(a[f"probs{i}"], a["y"],
+                                           a[f"reach{i}"], a["dw"], k)
+                new_state.append(tuple(getattr(view, attr)
+                                       for attr in STATE_ATTRS))
+            return new_cx, new_cy, tuple(new_state)
+
+        # ring buffers and learned state donated: one dispatch per
+        # committed tick, updated in place (sharding.jit_tick_update)
+        self._update = jit_tick_update(
+            _san.trace_probe("commit", student_step), self.mesh)
         self._bs_list = bs_list
 
     def _bucket(self, n: int) -> int:
@@ -1357,6 +1436,25 @@ class BatchedCascadeEngine:
                 self.commit_log.extend(
                     (rec.t, int(rec.lanes[int(s)]), t) for s in lanes)
 
+    def _own_state(self) -> None:
+        """Make ``self._owned`` the levels' learned state, donatable.
+
+        The update program donates the state it is given, so it is given
+        only trees its own last call returned.  A level whose state came
+        from anywhere else (construction, ``reset()``, a restore, a
+        caller's install) is first copied to private device buffers: the
+        arrays installed from outside outlive the commit."""
+        owned = self._owned or (None,) * len(self.levels)
+        state, copied = [], False
+        for lvl, mine in zip(self.levels, owned):
+            cur = tuple(getattr(lvl, attr) for attr in STATE_ATTRS)
+            if mine is None or any(a is not b for a, b in zip(cur, mine)):
+                cur = jax.tree.map(jnp.copy, cur)
+                copied = True
+            state.append(cur)
+        self._owned = tuple(state)
+        self.commit_stats["private_copies"] += copied
+
     def _commit(self, rec: _PendingTick, t: Optional[int] = None) -> None:
         """Apply a routed tick's expert annotations: ring-buffer scatter
         plus the per-tick weighted student/deferral updates, exactly the
@@ -1388,60 +1486,57 @@ class BatchedCascadeEngine:
             y_full = np.zeros(S, np.int32)
             y_full[sel_c] = np.maximum(y_sel, 0)
 
+            # the tick's host inputs, by name, for the update program
+            named = [(f"feats{i}", rec.feats[i]) for i in range(nlev)]
+            named += [("y_full", y_full),
+                      ("called", called_eff.astype(np.int32)),
+                      ("ptr", np.asarray(self._cache_ptr, np.int32))]
             # host mirrors first: sampling sees the post-insert fill level
-            ptr_pre = np.asarray(self._cache_ptr, np.int32)
-            idx_t = []
             for i, lvl in enumerate(self.levels):
                 size = lvl.spec.cache_size
                 self._cache_n[i] = min(self._cache_n[i] + k_ok, size)
                 self._cache_ptr[i] = (self._cache_ptr[i] + k_ok) % size
                 with TraceAnnotation("ocl.sample", tick=rec.t, level=i):
-                    idx_t.append(jnp.asarray(sample_cache_indices(
+                    named.append((f"idx{i}", sample_cache_indices(
                         rec.cache_rngs[i], self._cache_n[i],
                         self._bs_list[i]).astype(np.int32)))
-
-            with TraceAnnotation("ocl.update", tick=rec.t,
-                                 step="cache_scatter"):
-                new_cx, new_cy = self._scatter(
-                    tuple(self._cache_x), tuple(self._cache_y),
-                    tuple(self._put_lane(rec.feats[i]) for i in range(nlev)),
-                    self._put_lane(y_full), self._put_lane(called_eff),
-                    jnp.asarray(ptr_pre))
-            self._cache_x = list(new_cx)
-            self._cache_y = list(new_cy)
-            # batched, per-item-weighted updates through the SAME jitted
-            # step callables as the sequential reference (bit-identical
-            # state evolution; see module docstring)
             # reach[l] = prod_{k<l} dprob[k], float32 left fold like the
             # reference's running product
             reach = np.ones((nlev, S), np.float32)
             for i in range(1, nlev):
                 reach[i] = reach[i - 1] * rec.dprob[i - 1]
-            scaled = self.updates_per_tick == "scaled" and k_ok > 1
-            k_arr = jnp.asarray(float(k_ok), jnp.float32) if scaled else None
-            suffix = "_k" if scaled else ""
+            # the deferral steps' lanes, padded to the tick's bucket
             B_c = self._bucket(k)
-            for i, lvl in enumerate(self.levels):
-                kind = lvl.spec.kind
-                with TraceAnnotation("ocl.update", tick=rec.t, level=i,
-                                     step=f"{kind}.student_step{suffix}"):
-                    xb = self._cache_x[i][idx_t[i]]
-                    yb = self._cache_y[i][idx_t[i]]
-                    w = jnp.ones((self._bs_list[i],), jnp.float32)
-                    lvl.apply_student_update(xb, yb, w, k_arr)
-                with TraceAnnotation("ocl.update", tick=rec.t, level=i,
-                                     step=f"{kind}.deferral_step{suffix}"):
-                    probs_b = np.zeros((B_c, cfg.n_classes), np.float32)
-                    probs_b[:k] = rec.probs[i, sel_c]
-                    y_b = np.zeros(B_c, np.int32)
-                    y_b[:k] = np.maximum(y_sel, 0)
-                    reach_b = np.zeros(B_c, np.float32)
-                    reach_b[:k] = reach[i, sel_c]
-                    w_b = np.zeros(B_c, np.float32)
-                    w_b[:k] = ok.astype(np.float32)
-                    lvl.apply_deferral_update(
-                        self._put_lane(probs_b), self._put_lane(y_b),
-                        self._put_lane(reach_b), self._put_lane(w_b), k_arr)
+            y_b = np.zeros(B_c, np.int32)
+            y_b[:k] = np.maximum(y_sel, 0)
+            w_b = np.zeros(B_c, np.float32)
+            w_b[:k] = ok.astype(np.float32)
+            named += [("y", y_b), ("dw", w_b)]
+            for i in range(nlev):
+                probs_b = np.zeros((B_c, cfg.n_classes), np.float32)
+                probs_b[:k] = rec.probs[i, sel_c]
+                reach_b = np.zeros(B_c, np.float32)
+                reach_b[:k] = reach[i, sel_c]
+                # the student weights are an input, as they are to the
+                # reference's step, so XLA compiles the same weighted
+                # mean and does not fold a constant into it
+                named += [(f"w{i}", np.ones(self._bs_list[i], np.float32)),
+                          (f"probs{i}", probs_b), (f"reach{i}", reach_b)]
+            if self.updates_per_tick == "scaled" and k_ok > 1:
+                named.append(("k", np.float32(k_ok)))
+            with TraceAnnotation("ocl.update", tick=rec.t, step="commit"):
+                bufs, layout = _pack(named)
+                self._own_state()
+                new_cx, new_cy, new_state = self._update(
+                    tuple(self._cache_x), tuple(self._cache_y), self._owned,
+                    tuple(self._put_rep(b) for b in bufs), layout)
+            self._cache_x = list(new_cx)
+            self._cache_y = list(new_cy)
+            self._owned = new_state
+            for lvl, st in zip(self.levels, new_state):
+                for attr, v in zip(STATE_ATTRS, st):
+                    setattr(lvl, attr, v)
+            self.commit_stats["programs"] += 1
             rec.committed = k
             self._record_commit(rec, sel_c[ok], at)
             # params/dparams changed: any route forward dispatched before
@@ -1642,10 +1737,7 @@ class BatchedCascadeEngine:
             "cache_ptr": list(self._cache_ptr),
             "route_beta": [float(b) for b in self._route_beta],
             "route_items": self._route_items,
-            "commit_stats": {"lanes": self.commit_stats["lanes"],
-                             "age_sum": self.commit_stats["age_sum"],
-                             "age_max": self.commit_stats["age_max"],
-                             "wall_sum": self.commit_stats["wall_sum"]},
+            "commit_stats": dict(self.commit_stats),
             "commit_log": ([list(e) for e in self.commit_log]
                            if self.commit_log is not None else None),
             "pipeline_stats": dict(self.pipeline_stats),
@@ -1688,10 +1780,8 @@ class BatchedCascadeEngine:
         self._route_beta = [float(b) for b in meta["route_beta"]]
         self._route_items = int(meta["route_items"])
         cs = meta["commit_stats"]
-        self.commit_stats = {"lanes": int(cs["lanes"]),
-                             "age_sum": int(cs["age_sum"]),
-                             "age_max": int(cs.get("age_max", 0)),
-                             "wall_sum": float(cs["wall_sum"])}
+        self.commit_stats = {k: type(v)(cs.get(k, v))
+                             for k, v in _zero_commit_stats().items()}
         self.commit_log = ([tuple(e) for e in meta["commit_log"]]
                            if meta["commit_log"] is not None else None)
         self.pipeline_stats = {k: int(v)

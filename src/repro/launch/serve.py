@@ -225,7 +225,9 @@ def serve_stream_batched(dataset: str, samples: int, mu: float,
     if cs["lanes"]:
         print(f"annotation commits: {cs['lanes']} lanes, "
               f"mean age {cs['age_sum'] / cs['lanes']:.2f} ticks, "
-              f"mean latency {cs['wall_sum'] / cs['lanes'] * 1e3:.1f} ms")
+              f"mean latency {cs['wall_sum'] / cs['lanes'] * 1e3:.1f} ms, "
+              f"update programs {cs['programs']} "
+              f"(private state copies {cs['private_copies']})")
     fs = engine.fault_stats
     if any(fs.values()):
         print(f"fault stats: timeouts={fs['timeouts']} "

@@ -144,6 +144,24 @@ def jit_cache_scatter(fn, mesh: Optional[Mesh] = None):
                    out_shardings=replicated_sharding(mesh))
 
 
+def jit_tick_update(fn, mesh: Optional[Mesh] = None):
+    """Jit a tick's whole update pass ``fn(cx, cy, state, bufs, layout)``
+    with the ring buffers and every level's learned state donated.
+
+    One program per committed tick writes the ring buffers and steps the
+    students and deferral gates in place, so the host dispatches one call
+    and the device allocates no second copy of the state.  ``layout`` is
+    static (the shapes of the tick's packed host inputs ``bufs``).  With a
+    mesh the outputs are pinned replicated, like ``jit_cache_scatter``'s,
+    so each call's donated inputs keep the placement the last call gave
+    them.
+    """
+    if mesh is None:
+        return jax.jit(fn, donate_argnums=(0, 1, 2), static_argnums=(4,))
+    return jax.jit(fn, donate_argnums=(0, 1, 2), static_argnums=(4,),
+                   out_shardings=replicated_sharding(mesh))
+
+
 def host_prefetch(arrays) -> None:
     """Start async device->host copies for ``arrays`` (non-blocking).
 

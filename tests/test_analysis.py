@@ -622,10 +622,13 @@ class TestShardingContract:
             return x
         def jit_scatter(fn):
             return jax.jit(fn, donate_argnums=(0,))
+        def jit_update(fn):
+            return jax.jit(fn, donate_argnums=(0, 1))
     """
     INIT = """
-        from repro.sharding.specs import jit_scatter, lane_spec, put_lanes
-        __all__ = ["lane_spec", "put_lanes", "jit_scatter"]
+        from repro.sharding.specs import (jit_scatter, jit_update,
+                                          lane_spec, put_lanes)
+        __all__ = ["lane_spec", "put_lanes", "jit_scatter", "jit_update"]
     """
 
     def _tree(self, tmp_path, core_src: str):
@@ -704,6 +707,29 @@ class TestShardingContract:
         fs = self._findings(tmp_path)
         assert len(fs) == 1 and "donated position 0" in fs[0].message
         assert "_cache" in fs[0].message
+
+    def test_donated_state_read_before_rebind_flagged(self, tmp_path):
+        # the tick's update program donates the learned state too: a
+        # caller that reads self._state after the call, before rebinding
+        # it from the outputs, reads a dead buffer
+        self._tree(tmp_path, """
+            from repro.sharding import jit_update
+            class Engine:
+                def __init__(self, fn):
+                    self._update = jit_update(fn)
+                def commit(self):
+                    cache, state = self._update(self._cache, self._state)
+                    stale = self._state
+                    self._cache, self._state = cache, state
+                    return stale
+                def commit_clean(self):
+                    cache, state = self._update(self._cache, self._state)
+                    self._cache, self._state = cache, state
+                    return self._state
+        """)
+        fs = self._findings(tmp_path)
+        assert len(fs) == 1 and "read after" in fs[0].message
+        assert "self._state" in fs[0].message
 
     def test_real_core_tree_conforms(self):
         res = run_analysis(REPO_ROOT, paths=["src"],

@@ -274,7 +274,8 @@ class TestResetReuse:
         assert not np.any(eng.items_seen)
         assert not np.any(eng.J_cum)
         assert eng.commit_stats == {"lanes": 0, "age_sum": 0,
-                                    "age_max": 0, "wall_sum": 0.0}
+                                    "age_max": 0, "wall_sum": 0.0,
+                                    "programs": 0, "private_copies": 0}
         assert all(v == 0 for v in eng.pipeline_stats.values())
         assert eng._cache_n == [0] * len(eng.levels)
         assert eng._cache_ptr == [0] * len(eng.levels)
