@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import kinds
 import model
 
 B1, B2, EPS = 0.9, 0.999, 1e-8
@@ -85,19 +86,22 @@ def _forward(level, prec, student, dparams, x):
     return np.concatenate(outs_p)[:n], np.concatenate(outs_d)[:n]
 
 
-def _expert(spec, prec, params, docs):
+def _expert(casc, prec, params, docs):
+    spec = casc["expert"]
     matmul, rounding = prec.of(spec["precision"])
     n = len(docs)
     if n == 0:
-        return np.zeros((0, 2), np.float32)
-    ids = np.stack([model.hash_ids(d, spec["vocab"], spec["max_len"])
-                    for d in docs] + [np.zeros(spec["max_len"], np.int32)]
-                   * (-n % 64))
+        return np.zeros((0, casc["n_classes"]), np.float32)
+    shape, dtype = kinds.reference(spec["kind"]).row(spec,
+                                                     casc["n_features"])
+    x = np.concatenate([model.rows(spec["kind"], spec, docs,
+                                   casc["n_features"]),
+                        np.zeros((-n % 64,) + shape, dtype)])
     out = []
     with jax.default_matmul_precision(matmul):
-        for lo in range(0, len(ids), 64):
+        for lo in range(0, len(x), 64):
             out.append(np.asarray(model.expert_logits(
-                spec, params, ids[lo:lo + 64], rounding), np.float32))
+                spec, params, x[lo:lo + 64], rounding), np.float32))
     return np.concatenate(out)[:n]
 
 
@@ -251,7 +255,7 @@ def _xent(logits, labels, w):
 
 @functools.lru_cache(maxsize=None)
 def _student_grad_fn(kind: str, spec_items, rounding):
-    fn = model.LOGITS[kind]
+    fn = kinds.reference(kind).logits
     spec = dict(spec_items) if spec_items else None
     rnd = model.ROUNDINGS[rounding]
 
@@ -328,6 +332,15 @@ def _zeros_like(tree):
     return jax.tree.map(jnp.zeros_like, tree)
 
 
+def _opt_state(optimizer: str, p):
+    if optimizer == "ogd":
+        return {"count": 0}
+    return {"count": 0, "m": _zeros_like(p), "v": _zeros_like(p)}
+
+
+STEPS = {"ogd": _ogd, "adam": _adam}
+
+
 def tick_draws(seed: int, S: int, t: int, nlev: int):
     """Lane DAgger uniforms (nlev, S) and lane 0's cache generators."""
     u = np.empty((nlev, S))
@@ -365,16 +378,14 @@ def replay_learning(cfg: dict, mix: dict, seed: int, weights, docs_by_tick,
     nf = casc["n_features"]
     st = []
     for lv, w in zip(levels, weights["levels"]):
-        p = w["student"]
+        kind = kinds.reference(lv["kind"])
+        shape, dtype = kind.row(lv.get("spec"), nf)
         st.append({
-            "p": p, "dp": w["deferral"],
-            "opt": ({"count": 0} if lv["kind"] == "lr" else
-                    {"count": 0, "m": _zeros_like(p), "v": _zeros_like(p)}),
-            "dopt": {"count": 0, "m": _zeros_like(w["deferral"]),
-                     "v": _zeros_like(w["deferral"])},
-            "cx": np.zeros((lv["cache_size"],) + ((nf,) if lv["kind"] == "lr"
-                                                  else (lv["spec"]["max_len"],)),
-                           np.float32 if lv["kind"] == "lr" else np.int32),
+            "p": w["student"], "dp": w["deferral"],
+            "step": STEPS[kind.OPTIMIZER],
+            "opt": _opt_state(kind.OPTIMIZER, w["student"]),
+            "dopt": _opt_state("adam", w["deferral"]),
+            "cx": np.zeros((lv["cache_size"],) + shape, dtype),
             "cy": np.zeros((lv["cache_size"],), np.int32), "n": 0, "ptr": 0,
             "beta": casc["beta0"]})
     res = {"ticks": [], "calls": [], "grad_norms": None, "batches": [],
@@ -399,7 +410,7 @@ def replay_learning(cfg: dict, mix: dict, seed: int, weights, docs_by_tick,
         called = np.asarray(out["expert_called"], bool)
         y = np.asarray(out["expert_labels"], np.int64)
         sel = np.flatnonzero(called)
-        elog = _expert(casc["expert"], prec, weights["expert"],
+        elog = _expert(casc, prec, weights["expert"],
                        [docs[s] for s in sel])
         res["ticks"].append({"probs": probs, "dprob": dprob, "jumps": jumps,
                              "expert_logits": elog})
@@ -420,9 +431,8 @@ def replay_learning(cfg: dict, mix: dict, seed: int, weights, docs_by_tick,
                 idx = sample_cache(cache_rngs[i], s_["n"], bs)
                 g = _student_grad(lv, prec, s_["p"], s_["cx"][idx],
                                   s_["cy"][idx])
-                step = _ogd if lv["kind"] == "lr" else _adam
-                s_["p"], s_["opt"] = step(s_["p"], g, s_["opt"],
-                                          lv["student_lr"])
+                s_["p"], s_["opt"] = s_["step"](s_["p"], g, s_["opt"],
+                                                lv["student_lr"])
                 reach = np.ones(k, np.float32)
                 for j in range(i):
                     reach = reach * dprob[j, sel]
@@ -531,10 +541,9 @@ def learning_numbers(cfg, mix, seed, weights, docs_by_tick, outs_by_tick,
     docs_w, labels_w = window_labels
     flip_label = 0.0
     if docs_w:
-        ref_l = _expert(casc["expert"], Precision(False), weights["expert"],
-                        docs_w)
+        ref_l = _expert(casc, Precision(False), weights["expert"], docs_w)
         if control:
-            labels_w = np.argmax(_expert(casc["expert"], Precision(True),
+            labels_w = np.argmax(_expert(casc, Precision(True),
                                          weights["expert"], docs_w), -1)
         lab = max(lab, label_gap(ref_l, np.asarray(labels_w)))
         flip_label = label_gap(ref_l, 1 - np.asarray(labels_w))

@@ -13,6 +13,7 @@ import time
 import numpy as np
 
 import check
+import kinds
 import system
 
 
@@ -54,7 +55,7 @@ class Run:
                                       weights["levels"]):
                     dg = check.scaled_diff(lvl.dopt_state["m"], None,
                                            1 / (1 - check.B1))
-                    if lv["kind"] == "lr":
+                    if kinds.reference(lv["kind"]).OPTIMIZER == "ogd":
                         # OGD's first step is -lr * g
                         g = check.scaled_diff(lvl.params, w["student"],
                                               -1.0 / lv["student_lr"])

@@ -1,7 +1,10 @@
-"""Device ms per tick in the expert: ``ModelExpert``'s forward (a jitted
-lambda) and the argmax over its probabilities."""
+"""Device ms per tick in the expert: the XLA programs of its forward, as
+its kind's adapter names them (``PROGRAMS`` of
+``bench/kinds/<kind>_program.py``)."""
 import importlib.util
 from pathlib import Path
+
+import kinds
 
 _spec = importlib.util.spec_from_file_location(
     "bench_metric_lib", Path(__file__).with_name("_lib.py"))
@@ -10,4 +13,5 @@ _spec.loader.exec_module(_lib)
 
 
 def read(ctx):
-    return _lib.module_ms_per_tick(ctx, {"_lambda", "_argmax"})
+    kind = ctx["cfg"]["cascade"]["expert"]["kind"]
+    return _lib.module_ms_per_tick(ctx, kinds.program(kind).PROGRAMS)

@@ -4,10 +4,12 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 from the root of a checkout.  The cell (``BENCHMARK.json`` ``workloads``)
-names a configuration file and a traffic mix under ``bench/``; per-layer
+names a configuration file and a traffic mix under ``bench/``; each level
+and the expert is of a kind, ``bench/kinds/<kind>.py`` (its reference)
+and ``<kind>_program.py`` (its adapter to the program); per-layer
 metrics are readers under ``bench/metrics/<name>.py``; the limits of the
 correctness comparison are ``bench/limits/<cell>.json``.  Adding a cell,
-mix, configuration or metric is adding such files and entries.
+mix, configuration, kind or metric is adding such files and entries.
 
 Set-up (everything before the window, compilation included, reported as
 ``setup_s``) builds the repo's ``BatchedCascadeEngine`` with weights made
@@ -34,6 +36,8 @@ import shutil
 import sys
 import time
 from pathlib import Path
+
+import kinds
 
 T_START = time.perf_counter()
 CACHE_BYTES = 8 << 30           # room for every cell's compiled programs
@@ -78,6 +82,10 @@ def cell_from_files(name: str, config_file: str, traffic: str,
     casc = cfg["cascade"]
     for lv in casc["levels"] + [casc["expert"]]:
         lv.setdefault("precision", casc["precision"])
+        try:
+            kinds.check(lv["kind"])
+        except LookupError as e:
+            raise SetupError(f"{config_file}: kind {lv['kind']!r}: {e}")
     for lv in casc["levels"]:
         lv["spec"] = casc.get(lv["kind"])
     metrics = [m for m in spec["per_layer"]
@@ -195,8 +203,8 @@ def run_cell(c: dict, seed: int, seconds: float, trace: bool,
     docs, _ = traffic.corpus(mix, seed)
     phases("corpus")
     weights = model.make_weights(casc, seed)
-    weights["expert"] = model.balance_expert(
-        weights["expert"], casc["expert"], docs[:256])
+    weights["expert"] = model.balance_expert(weights["expert"], casc,
+                                             docs[:256])
     jax.block_until_ready(weights)
     phases("weights")
     eng = system.build(casc, mix, seed, weights)

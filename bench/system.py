@@ -1,7 +1,8 @@
 """The system under test: the repo's engine, built from a configuration
 file and a traffic mix, with the benchmark's weights installed.
 
-This is the only file of the benchmark that imports the program.
+With the kinds' adapters (``bench/kinds/<kind>_program.py``), this is the
+only file of the benchmark that imports the program.
 """
 from __future__ import annotations
 
@@ -10,30 +11,32 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+import kinds
+
 
 def build(cascade: dict, mix: dict, seed: int, weights):
     """A ``BatchedCascadeEngine`` for this cell, its weights replaced by
-    ``weights`` (``model.make_weights``)."""
+    ``weights`` (``model.make_weights``); each level's and the expert's
+    program objects come from their kinds' adapters
+    (``bench/kinds/<kind>_program.py``)."""
     from repro.core.batched import BatchedCascadeEngine
     from repro.core.cascade import CascadeConfig, LevelSpec
-    from repro.core.experts import ModelExpert
-    from repro.models.students import TinyTFSpec
 
     C = cascade["n_classes"]
     fields = {f.name for f in dataclasses.fields(LevelSpec)}
+    specs = {}
+    for lv in cascade["levels"]:
+        specs.update(kinds.program(lv["kind"]).config(lv.get("spec"), C))
     cfg = CascadeConfig(
         levels=tuple(LevelSpec(**{k: v for k, v in lv.items() if k in fields})
                      for lv in cascade["levels"]),
         n_classes=C, expert_cost=cascade["expert_cost"], mu=mix["mu"],
         beta0=cascade["beta0"], n_features=cascade["n_features"],
-        tf_spec=TinyTFSpec(**cascade["tinytf"], n_classes=C),
         sample_actions=cascade["sample_actions"],
-        hard_budget=mix["hard_budget"], seed=seed % (2 ** 31))
-    es = {k: v for k, v in cascade["expert"].items()
-          if k not in ("kind", "precision")}
-    expert = ModelExpert(params=weights["expert"],
-                         spec=TinyTFSpec(**es, n_classes=C),
-                         cost=cascade["expert_cost"])
+        hard_budget=mix["hard_budget"], seed=seed % (2 ** 31), **specs)
+    ex = cascade["expert"]
+    expert = kinds.program(ex["kind"]).build(weights["expert"], ex, C,
+                                             cascade["expert_cost"])
     eng = BatchedCascadeEngine(
         cfg, expert, n_streams=mix["lanes"],
         updates_per_tick=mix["updates_per_tick"], max_delay=mix["max_delay"],
@@ -80,10 +83,10 @@ class RouteRecorder:
 
 
 def warm_shapes(eng) -> None:
-    """Compile every shape this cell's ticks can use, without changing
-    the engine's state: each level's route pass at every lane bucket,
-    the update steps, the ring scatter and the expert at every batch
-    size the tick can hand it."""
+    """Compile each level's route pass at every lane bucket the cell's
+    ticks can hand it, without changing the engine's state.  The tick's
+    update program and the expert's forward at each batch size are
+    compiled by the ticks and expert calls of ``drivers.Run.warm``."""
     S = eng.n_streams
     buckets = sorted({eng._bucket(n) for n in range(1, S + 1)})
     outs = []
@@ -93,30 +96,6 @@ def warm_shapes(eng) -> None:
         for b in buckets:
             outs.append(eng._predict_defer[i](
                 lvl.params, lvl.dparams, jnp.zeros((b,) + shape, dtype)))
-    C = eng.cfg.n_classes
-    for i, lvl in enumerate(eng.levels):
-        bs = eng._bs_list[i]
-        xb = eng._cache_x[i][jnp.zeros((bs,), jnp.int32)]
-        yb = eng._cache_y[i][jnp.zeros((bs,), jnp.int32)]
-        outs.append(lvl._student_step(lvl.params, lvl.opt_state, xb, yb,
-                                      jnp.ones((bs,), jnp.float32)))
-        for b in buckets:
-            z = jnp.zeros((b,), jnp.float32)
-            outs.append(lvl._deferral_step(
-                lvl.dparams, lvl.dopt_state,
-                jnp.zeros((b, C), jnp.float32), jnp.zeros((b,), jnp.int32),
-                z, z))
-    feats = tuple(jnp.zeros((S,) + lvl.cache_x.shape[1:],
-                            lvl.cache_x.dtype) for lvl in eng.levels)
-    outs.append(eng._scatter(
-        tuple(jnp.array(c) for c in eng._cache_x),
-        tuple(jnp.array(c) for c in eng._cache_y), feats,
-        jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool),
-        jnp.zeros((len(eng.levels),), jnp.int32)))
-    spec = eng.expert.spec
-    for k in range(1, S + 1):
-        outs.append(eng.expert._predict(
-            eng.expert.params, jnp.ones((k, spec.max_len), jnp.int32)))
     jax.block_until_ready(outs)
 
 

@@ -1,5 +1,7 @@
 """Tiny copies of the cells for CPU tests: a cell's own configuration,
-traffic mix, driver, regime and limits, at widths a test run can hold."""
+traffic mix, driver, regime and limits (``BENCHMARK.json``), at the sizes
+each kind's ``TINY`` gives and with a short mix."""
+import json
 import os
 import sys
 from pathlib import Path
@@ -9,28 +11,30 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-TINY = {"tinytf": dict(vocab=256, max_len=32, d_model=32, n_heads=2,
-                       n_layers=1, d_ff=64),
-        "expert": dict(vocab=256, max_len=32, d_model=32, n_heads=2,
-                       n_layers=1, d_ff=64)}
-
-# name -> (configuration file, traffic mix), as in BENCHMARK.json
-CELLS = {"bert-learn-s64": ("bench/configs/ocl-bert-base.json", "learn-s64")}
+# name -> (configuration file, traffic mix), from BENCHMARK.json
+_SPEC = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+_FILES = {c["name"]: c["file"] for c in _SPEC["configs"]}
+CELLS = {w["name"]: (_FILES[w["config"]], w["traffic"])
+         for w in _SPEC["workloads"]}
 
 
-def tiny_cell(name: str) -> dict:
-    """The cell cut to CPU test size."""
-    import run
-    c = run.cell_from_files(name, *CELLS[name])
+def tiny(c: dict) -> dict:
+    """A cell (``run.cell_from_files``) cut to CPU test size."""
+    import kinds
     casc = c["cfg"]["cascade"]
-    for k, v in TINY.items():
-        if k in casc:
-            casc[k].update(v)
     for lv in casc["levels"]:
-        lv["spec"] = casc.get(lv["kind"])
+        if lv["spec"] is not None:
+            lv["spec"].update(kinds.reference(lv["kind"]).TINY)
+    casc["expert"].update(kinds.reference(casc["expert"]["kind"]).TINY)
     c["mix"]["lanes"] = 8
     c["mix"]["corpus"].update(n_docs=512, mean_len=20)
     return c
+
+
+def tiny_cell(name: str) -> dict:
+    """The cell ``name`` cut to CPU test size."""
+    import run
+    return tiny(run.cell_from_files(name, *CELLS[name]))
 
 
 @pytest.fixture(autouse=True, scope="session")
